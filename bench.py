@@ -59,7 +59,7 @@ history offline).
 ``python bench.py --smoke`` runs the C24 bitrot canary instead (no gates;
 wired into tier-1 via tests/test_bench_smoke.py); ``python bench.py
 --compile-report`` prints cold-vs-warm compile seconds for the
-``JAXSTREAM_COMPILE_CACHE`` persistent-cache opt-in; ``python bench.py
+persistent compile cache the package places on import; ``python bench.py
 --precision-report`` prints the round-10 precision ladder (f32 /
 bf16-stage / mixed16-carry / stacked measured side by side at C384
 with precision-corrected rooflines — ``bench_precision_report``).
@@ -136,7 +136,7 @@ def _open_telemetry(path: str):
 def _roofline_json(steps_per_sec: float, n: int, scale: float = 1.0,
                    bytes_scale: float = 1.0, ensemble: int = 1,
                    carry_bytes: int = None, nu4: str = None,
-                   precision: str = None):
+                   precision: str = None, device_kind: str = None):
     """Roofline numbers for one covariant-fused-stepper rate, as JSON.
 
     The analytic kernel count against the VPU roof (Pallas custom calls
@@ -171,7 +171,8 @@ def _roofline_json(steps_per_sec: float, n: int, scale: float = 1.0,
     ``jaxstream.obs.perf.roofline_json`` — the ONE definition of cost
     accounting the probe CLIs and the serving cost stamps share; this
     wrapper keeps bench's never-fail-a-variant contract.  Returns None
-    when the profiling helpers are unavailable.
+    when the profiling helpers are unavailable, and on a CPU run (no
+    roofline share; the peaks are keyed by ``device_kind``).
     """
     try:
         from jaxstream.obs.perf import roofline_json
@@ -180,7 +181,7 @@ def _roofline_json(steps_per_sec: float, n: int, scale: float = 1.0,
                              bytes_scale=bytes_scale,
                              ensemble=ensemble,
                              carry_bytes=carry_bytes, nu4=nu4,
-                             precision=precision)
+                             precision=precision, device_kind=device_kind)
     except Exception as e:
         log(f"bench: variant roofline unavailable ({e})")
         return None
@@ -486,10 +487,12 @@ def bench_tc5(n=384, dt=BENCH_DT, warm_steps=10, timed_steps=24000,
         f"{steps_per_sec * 60.0 / 86400.0:.4f} sim-days/sec/chip")
     try:  # roofline context (deck p.19's analysis frame; best-effort)
         from jaxstream.utils.profiling import (
-            TPU_V5E, TPU_V5E_VPU, Roofline, analytic_cov_step_cost,
-            roofline)
+            Roofline, analytic_cov_step_cost, device_roofs, roofline)
 
-        if rung in ("cov_fused", "cart_fused", "pallas_rhs"):
+        roofs = device_roofs(jax.devices()[0].device_kind)
+        if roofs is None:
+            log("bench: no roofline share on cpu")
+        elif rung in ("cov_fused", "cart_fused", "pallas_rhs"):
             # These rungs' math lives in Pallas kernels — invisible to
             # XLA's cost_analysis (round 1 printed a ~200x-off roofline
             # that way).  Use the hand-counted static-stencil cost
@@ -501,7 +504,7 @@ def bench_tc5(n=384, dt=BENCH_DT, warm_steps=10, timed_steps=24000,
             scale = 1.0 if rung == "cov_fused" else 1.4
             c = analytic_cov_step_cost(n)
             r = Roofline(c["flops"] * scale, c["bytes"] * scale,
-                         1.0 / steps_per_sec, TPU_V5E_VPU)
+                         1.0 / steps_per_sec, roofs["vpu"])
             tag = ("" if rung == "cov_fused"
                    else f" (x{scale} Cartesian-formulation estimate)")
             log("bench: analytic kernel count "
@@ -510,7 +513,7 @@ def bench_tc5(n=384, dt=BENCH_DT, warm_steps=10, timed_steps=24000,
                 "calls) " + r.report())
         else:  # pure-jnp rung: XLA sees every op, cost_analysis is real
             r = roofline(jax.jit(step), out, jnp.float32(0.0),
-                         seconds=1.0 / steps_per_sec, roof=TPU_V5E)
+                         seconds=1.0 / steps_per_sec, roof=roofs["mxu"])
             log("bench: XLA-cost_analysis roofline " + r.report())
     except Exception as e:
         log(f"bench: roofline unavailable ({e})")
@@ -1112,7 +1115,7 @@ def bench_serving(n=96, dt=300.0, bucket=16, n_requests=48, seg=8,
                     "steady_recompiles": (
                         srv.compile_count()
                         - srv.stats["warmup_compiles"]),
-                    "impl": srv._impls.get(group),
+                    "impl": srv._impl_for(group, srv._plans[b]),
                     "wall_s": round(wall, 3),
                 }
                 if srv.stats["completed"] != n_requests:
@@ -1726,15 +1729,13 @@ def bench_io(n=48, dt=600.0, nsteps=96, stride=12, warm=12, ic="tc2",
 def compile_report(n=24):
     """``--compile-report``: cold vs warm compile seconds, one JSON line.
 
-    Measures the persistent compilation cache (enabled by
-    ``JAXSTREAM_COMPILE_CACHE=/path``, picked up on jaxstream import):
+    Measures the persistent compilation cache the package placed on
+    import (``JAX_COMPILATION_CACHE_DIR``, else ``<repo>/.jax_cache``):
     compile a representative stepper executable cold, drop jax's
     in-memory caches (``jax.clear_caches()``), compile again — warm
     hits the persistent cache when enabled, recompiles when not, so the
-    cold/warm ratio IS the cache's value.  Same-process reuse only: on
-    this image's jaxlib a *different* process deserializing CPU cache
-    entries segfaults (tests/conftest.py round-8 note), which is why
-    the cache is an env-var opt-in rather than a default.
+    cold/warm ratio IS the cache's value.  Every executable is cached
+    for the report, however quick its compile.
     """
     import os
 
@@ -1746,8 +1747,10 @@ def compile_report(n=24):
     from jaxstream.models.shallow_water import ShallowWater
     from jaxstream.physics.initial_conditions import williamson_tc2
     from jaxstream.stepping import integrate
+    from jaxstream.utils.jax_compat import enable_compile_cache
 
-    cache_dir = os.environ.get("JAXSTREAM_COMPILE_CACHE", "")
+    enabled = bool(jax.config.jax_enable_compilation_cache)
+    cache_dir = enable_compile_cache(jax.config.jax_compilation_cache_dir)
     grid = build_grid(n, halo=2, radius=EARTH_RADIUS, dtype=jnp.float32)
     h_ext, v_ext = williamson_tc2(grid, EARTH_GRAVITY, EARTH_OMEGA)
     model = ShallowWater(grid, gravity=EARTH_GRAVITY, omega=EARTH_OMEGA)
@@ -1764,11 +1767,9 @@ def compile_report(n=24):
     warm = time.perf_counter() - t0
     rec = {
         "metric": "compile_report",
-        "cache_dir": cache_dir or None,
-        "cache_enabled": bool(cache_dir),
-        "n_cache_entries": (len(os.listdir(cache_dir))
-                            if cache_dir and os.path.isdir(cache_dir)
-                            else 0),
+        "cache_dir": cache_dir,
+        "cache_enabled": enabled,
+        "n_cache_entries": len(os.listdir(cache_dir)),
         "cold_compile_s": round(cold, 3),
         "warm_compile_s": round(warm, 3),
         "speedup": round(cold / warm, 2) if warm > 0 else None,
@@ -1776,8 +1777,8 @@ def compile_report(n=24):
     log(f"compile report (C{n} classic SSPRK3 segment): cold {cold:.2f}s "
         f"-> warm {warm:.2f}s "
         + (f"(persistent cache at {cache_dir}, "
-           f"{rec['n_cache_entries']} entries)" if cache_dir
-           else "(JAXSTREAM_COMPILE_CACHE unset: warm = plain recompile)"))
+           f"{rec['n_cache_entries']} entries)" if enabled
+           else "(cache disabled: warm = plain recompile)"))
     print(json.dumps(rec))
     return 0
 
@@ -2440,9 +2441,12 @@ def bench_contract_check(smoke=False):
         else:
             script = os.path.join(os.path.dirname(
                 os.path.abspath(__file__)), "scripts", "analyze.py")
+            # A structural CPU check: pinned to the CPU, so the child
+            # never reaches for the chip this process holds.
             r = subprocess.run(
                 [_sys.executable, script] + argv,
-                capture_output=True, text=True, timeout=1800)
+                capture_output=True, text=True, timeout=1800,
+                env=dict(os.environ, JAX_PLATFORMS="cpu"))
             if r.returncode not in (0, 1) or not r.stdout.strip():
                 tail = "\n".join((r.stdout + r.stderr).splitlines()[-5:])
                 return {"skipped": f"analyze subprocess failed: {tail}"}
@@ -2507,7 +2511,8 @@ def bench_multichip():
                 [_sys.executable, script, "--iters", "30", "--steps",
                  "10", "--temporal-block", "2", "--members", "2",
                  "--json"],
-                capture_output=True, text=True, timeout=1200)
+                capture_output=True, text=True, timeout=1200,
+                env=dict(os.environ, JAX_PLATFORMS="cpu"))
             if r.returncode != 0:
                 tail = "\n".join((r.stdout + r.stderr).splitlines()[-5:])
                 return {"skipped": f"cpu-smoke subprocess failed: {tail}"}

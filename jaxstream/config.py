@@ -271,8 +271,8 @@ class ServeConfig:
     11) — scenario requests packed into the member axis the way LLM
     servers pack prompts into a batch.  The server keeps one compiled
     masked-segment stepper warm per batch-size bucket (steady-state
-    serving triggers ZERO recompiles once the bucket set is warm;
-    ``JAXSTREAM_COMPILE_CACHE`` makes the warmup itself cheap across
+    serving triggers ZERO recompiles once the bucket set is warm; the
+    persistent compile cache makes the warmup itself cheap across
     restarts) and refills a finished member's slot from the bounded
     request queue at the next segment boundary (docs/USAGE.md
     "Serving")."""
@@ -355,11 +355,9 @@ class ServeConfig:
     # off (byte-identical warmup to round 20).
     warm_pool: str = ""
     # Round 21: jax persistent-compilation-cache directory, the warm
-    # pool's third rung.  Gated behind a SUBPROCESS feature probe:
-    # this image's jaxlib 0.4.37 is documented to segfault when a
-    # different process deserializes CPU cache entries (the jax_compat
-    # quarantine note), so the rung only engages after a child-process
-    # write+read probe exits clean.  '' = rung disabled.
+    # pool's third rung.  A JAX_COMPILATION_CACHE_DIR set from outside
+    # wins: the cache stays there and this only switches the rung on.
+    # '' = rung disabled.
     compile_cache: str = ""
     # Round 21: background speculative compilation of ADJACENT plans
     # (the next configured bucket up/down from the active cap) on a

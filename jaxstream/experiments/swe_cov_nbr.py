@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..utils.jax_compat import tpu_compiler_params
-
 from ..geometry.connectivity import (
     EDGE_E,
     EDGE_N,
@@ -419,7 +417,7 @@ def make_cov_stage_nbr(
             jax.ShapeDtypeStruct((6, m, m), jnp.float32),
             jax.ShapeDtypeStruct((2, 6, m, m), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=110 * 1024 * 1024,
         ),
         interpret=interpret,

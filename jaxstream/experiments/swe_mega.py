@@ -33,8 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..utils.jax_compat import tpu_compiler_params
-
 from ..geometry.connectivity import (
     EDGE_E,
     EDGE_N,
@@ -365,7 +363,7 @@ def make_fused_ssprk3_cov_mega(
             jax.ShapeDtypeStruct((6, 6 * h, n), jnp.float32),
             jax.ShapeDtypeStruct((6, n, 6 * h), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=120 * 1024 * 1024,
         ),
         interpret=interpret,

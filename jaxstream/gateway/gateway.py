@@ -715,8 +715,8 @@ class Gateway:
         memory = srv.memory_snapshot()
         if memory is not None:
             snap["memory"] = memory
-        # Round 21 (warm pools): hit/miss/rung counters, probe
-        # verdicts, and the speculative compiler's build log — only
+        # Round 21 (warm pools): hit/miss/rung counters and the
+        # speculative compiler's build log — only
         # stamped when a pool is configured, so a pool-less
         # deployment's stats payload stays byte-identical to round 20.
         warm = srv.warmpool_summary()

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from ..geometry.cubed_sphere import CubedSphereGrid
@@ -106,7 +107,9 @@ class SWEBase(Model):
         # evaluate there; otherwise we fill them once here).
         if b_ext is None:
             b_ext = jnp.zeros_like(grid.sqrtg)
-        self.b_ext = self.exchange(b_ext)
+        # One compiled exchange, not ~30 op-by-op dispatches that each
+        # compile on first use (a server's cold start pays them all).
+        self.b_ext = jax.jit(self.exchange)(b_ext)
 
     def _make_pallas_rhs(self, interpret: bool):  # pragma: no cover
         raise NotImplementedError

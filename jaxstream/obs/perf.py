@@ -276,22 +276,33 @@ def roofline_json(steps_per_sec: float, n: int, scale: float = 1.0,
                   bytes_scale: float = 1.0, ensemble: int = 1,
                   carry_bytes: Optional[int] = None,
                   nu4: Optional[str] = None,
-                  precision: Optional[str] = None) -> dict:
+                  precision: Optional[str] = None,
+                  device_kind: Optional[str] = None) -> Optional[dict]:
     """Roofline numbers for one covariant-stepper rate, as JSON — the
     ONE implementation behind ``bench.py``'s per-variant entries and
     the probe CLIs (round-19 dedupe satellite; the knob semantics are
     documented on ``bench._roofline_json``, which now delegates here).
-    Raises on unavailability — callers decide how loudly to degrade.
-    """
-    from ..utils.profiling import (TPU_V5E_VPU, Roofline,
-                                   analytic_cov_step_cost,
-                                   mixed_vpu_roof)
 
+    ``device_kind`` (default: the first device's) picks the peaks from
+    ``utils.profiling.DEVICE_ROOFS``; a CPU run returns None (no
+    roofline share) and an unknown kind raises — callers decide how
+    loudly to degrade.
+    """
+    from ..utils.profiling import (Roofline, analytic_cov_step_cost,
+                                   device_roofs, mixed_vpu_roof)
+
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    roofs = device_roofs(device_kind)
+    if roofs is None:
+        return None
     c = analytic_cov_step_cost(n, ensemble=ensemble,
                                carry_bytes=carry_bytes, nu4=nu4,
                                precision=precision)
     r = Roofline(c["flops"] * scale, c["bytes"] * scale * bytes_scale,
-                 1.0 / steps_per_sec, TPU_V5E_VPU)
+                 1.0 / steps_per_sec, roofs["vpu"])
     out = {
         "achieved_tflops": round(r.achieved_tflops, 3),
         "pct_of_compute_roof": round(
@@ -303,7 +314,8 @@ def roofline_json(steps_per_sec: float, n: int, scale: float = 1.0,
     if carry_bytes is not None and carry_bytes != 4:
         out["carry_bytes"] = carry_bytes
     if precision == "bf16":
-        mroof = mixed_vpu_roof(c["bf16_flop_fraction"])
+        mroof = mixed_vpu_roof(c["bf16_flop_fraction"], roofs["vpu"],
+                               roofs["vpu_bf16"])
         out["bf16_flop_fraction"] = round(c["bf16_flop_fraction"], 3)
         out["mixed_roof_tflops"] = round(mroof.peak_tflops, 2)
         out["pct_of_mixed_roof"] = round(

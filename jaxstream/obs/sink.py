@@ -126,12 +126,10 @@ RECORD_KINDS: Dict[str, tuple] = {
     # ``serve.warm_pool``): every rung decision the degradation ladder
     # takes is typed, never silent.  "event" is 'hit' / 'miss' /
     # 'save' / 'corrupt' (torn entry detected, deleted, recompiled) /
-    # 'probe' (a cross-process rung feature-probe verdict) /
     # 'fallback' (a rung refused — carries "reason"); "rung" is
     # 'aot' / 'stablehlo' / 'compile_cache' / 'cold'; "plan" is the
-    # bucket's plan key (null for pool-level events like probes).
-    # Optional: "key" (the entry digest), "reason", "bytes", "ok",
-    # "detail", "cached".
+    # bucket's plan key (null for pool-level events).
+    # Optional: "key" (the entry digest), "reason", "bytes".
     "warmpool": ("event", "rung", "plan"),
     # One headroom enforcement decision (round 21): resize() or the
     # speculative compiler refused a bucket whose stamped per-chip

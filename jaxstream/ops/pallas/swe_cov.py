@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...utils.jax_compat import named_scope, tpu_compiler_params
+from ...utils.jax_compat import named_scope
 
 from ...geometry.connectivity import (
     EDGE_E,
@@ -512,7 +512,7 @@ def make_cov_rhs_pallas(
             jax.ShapeDtypeStruct((nf, n, n), jnp.float32),
             jax.ShapeDtypeStruct((2, nf, n, n), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         interpret=interpret,
@@ -614,7 +614,7 @@ def make_cov_rhs_pallas_local(
             jax.ShapeDtypeStruct((1, n, n), jnp.float32),
             jax.ShapeDtypeStruct((2, 1, n, n), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         interpret=interpret,
@@ -737,7 +737,7 @@ def make_cov_rhs_interior_local(
             jax.ShapeDtypeStruct((1, ni, ni), jnp.float32),
             jax.ShapeDtypeStruct((2, 1, ni, ni), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         interpret=interpret,
@@ -1254,7 +1254,7 @@ def make_cov_stage_inkernel(
             jax.ShapeDtypeStruct((2, 6, m, m), jnp.float32),
             jax.ShapeDtypeStruct((6, R, n), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=110 * 1024 * 1024,
         ),
         interpret=interpret,
@@ -2012,7 +2012,7 @@ def make_cov_stage_compact(
             jax.ShapeDtypeStruct((groups, 6 * h, n), sdt),
             jax.ShapeDtypeStruct((groups, n, 6 * h), sdt),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=110 * 1024 * 1024,
         ),
         interpret=interpret,
@@ -2406,7 +2406,7 @@ def make_cov_stage_nu4(
             jax.ShapeDtypeStruct((6, 6 * h, n), jnp.float32),
             jax.ShapeDtypeStruct((6, n, 6 * h), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=110 * 1024 * 1024,
         ),
         interpret=interpret,
@@ -2428,7 +2428,7 @@ def make_cov_stage_nu4(
             jax.ShapeDtypeStruct((6, 6 * h, n), jnp.float32),
             jax.ShapeDtypeStruct((6, n, 6 * h), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=110 * 1024 * 1024,
         ),
         interpret=interpret,
@@ -2556,7 +2556,7 @@ def make_cov_nu4_filter(
             jax.ShapeDtypeStruct((6, 6 * h, n), sdt),
             jax.ShapeDtypeStruct((6, n, 6 * h), sdt),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=110 * 1024 * 1024,
         ),
         interpret=interpret,
@@ -2860,7 +2860,7 @@ def make_cov_stage_refused_nu4(
             jax.ShapeDtypeStruct((6, 6 * h, n), sdt),
             jax.ShapeDtypeStruct((6, n, 6 * h), sdt),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=110 * 1024 * 1024,
         ),
         interpret=interpret,

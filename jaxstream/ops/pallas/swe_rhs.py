@@ -34,8 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...utils.jax_compat import tpu_compiler_params
-
 from ...geometry.cubed_sphere import FACE_AXES, extended_coords
 from ..reconstruct import plr_face_states, ppm_face_states
 
@@ -442,7 +440,7 @@ def make_swe_rhs_pallas(
         # stencil intermediates — above the compiler's 16 MB default but
         # well inside the chip's 128 MB VMEM.  (C768+ would need row-band
         # tiling instead.)
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         interpret=interpret,

@@ -38,8 +38,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...utils.jax_compat import tpu_compiler_params
-
 from ...geometry.connectivity import (
     EDGE_E,
     EDGE_N,
@@ -154,7 +152,7 @@ def make_swe_stage_pallas(
         ],
         # Same scoped-VMEM story as the RHS kernel (swe_rhs.py): whole-face
         # stencil intermediates at C384 exceed the 16 MB default.
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         interpret=interpret,
@@ -438,7 +436,7 @@ def make_swe_stage_inkernel(
             jax.ShapeDtypeStruct((3, 6, 2, h, n), jnp.float32),
             jax.ShapeDtypeStruct((3, 6, 2, n, h), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=110 * 1024 * 1024,
         ),
         interpret=interpret,

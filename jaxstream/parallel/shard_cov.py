@@ -33,10 +33,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..utils.jax_compat import named_scope, shard_map
+from ..utils.jax_compat import named_scope
 
 from ..geometry.connectivity import (
     EDGE_E,
@@ -53,7 +53,8 @@ __all__ = ["CovShardProgram", "make_cov_shard_exchange",
            "make_cov_shard_exchange_phases",
            "make_cov_shard_exchange_batched",
            "make_sharded_cov_stepper", "make_sharded_cov_deep_stepper",
-           "make_sharded_cov_ensemble_stepper", "deep_extend_static"]
+           "make_sharded_cov_ensemble_stepper", "deep_extend_static",
+           "pallas_interpret_for"]
 
 _OUT_SIGN = {EDGE_S: -1.0, EDGE_W: -1.0, EDGE_N: 1.0, EDGE_E: 1.0}
 
@@ -577,6 +578,24 @@ def make_sharded_cov_deep_stepper(model, setup, dt: float,
     return step
 
 
+def pallas_interpret_for(platform: str) -> bool:
+    """Whether the sharded tiers' Pallas kernels run interpreted.
+
+    Explicit per platform: compiled Mosaic on ``'tpu'``, the Pallas
+    interpreter on ``'cpu'`` (the test tier), and an error anywhere
+    else — a device the kernels were never written for must not
+    silently run the interpreter.
+    """
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise ValueError(
+        f"the covariant shard tiers' Pallas kernels run on 'tpu' "
+        f"(compiled) or 'cpu' (interpret mode); got platform "
+        f"{platform!r}")
+
+
 def _make_cov_face_rhs(model, grid, program: CovShardProgram, overlap,
                        platform):
     """Per-face local RHS closure of the explicit face tier.
@@ -593,9 +612,10 @@ def _make_cov_face_rhs(model, grid, program: CovShardProgram, overlap,
     exchange = make_cov_shard_exchange(program)
     from ..ops.pallas.swe_cov import make_cov_rhs_pallas
 
+    interpret = pallas_interpret_for(platform)
     rhs_local = make_cov_rhs_pallas(
         grid, model.gravity, model.omega, scheme=model.scheme,
-        limiter=model.limiter, interpret=(platform != "tpu"),
+        limiter=model.limiter, interpret=interpret,
         n_faces=1, external_sym=True,
     )
     if overlap:
@@ -607,7 +627,7 @@ def _make_cov_face_rhs(model, grid, program: CovShardProgram, overlap,
         rhs_interior = make_cov_rhs_interior_local(
             n, halo, float(grid.dalpha), float(grid.radius),
             model.gravity, model.omega, scheme=model.scheme,
-            limiter=model.limiter, interpret=(platform != "tpu"))
+            limiter=model.limiter, interpret=interpret)
         rhs_band = make_cov_rhs_band_local(
             n, halo, float(grid.dalpha), float(grid.radius),
             model.gravity, model.omega, scheme=model.scheme,
@@ -684,7 +704,7 @@ def make_sharded_cov_stepper(model, setup, dt: float, overlap=None,
     the usual interior pytree ``{"h": (6, n, n), "u": (2, 6, n, n)}``
     sharded over the panel axis.  Each SSPRK3 stage = one explicit
     4-ppermute exchange + the fused covariant Pallas RHS kernel on the
-    local face (interpret mode off-TPU) + the stage combination.
+    local face (interpret mode on CPU) + the stage combination.
 
     ``overlap`` (default: the setup's ``overlap_exchange`` flag): issue
     the 4 ppermute stages first, run the interior-only RHS kernel (the
@@ -720,7 +740,7 @@ def make_sharded_cov_stepper(model, setup, dt: float, overlap=None,
         overlap = getattr(setup, "overlap_exchange", False)
     mesh = setup.mesh
     program = CovShardProgram(grid)
-    platform = getattr(mesh.devices.flat[0], "platform", "cpu")
+    platform = mesh.devices.flat[0].platform
     f_loc = _make_cov_face_rhs(model, grid, program, overlap, platform)
     frames_z = jnp.asarray(
         np.asarray(FACE_AXES)[:, None, :, 2], jnp.float32)
@@ -823,7 +843,7 @@ def make_sharded_cov_ensemble_stepper(model, setup, dt: float,
         overlap = getattr(setup, "overlap_exchange", False)
     mesh = setup.mesh
     program = CovShardProgram(grid)
-    platform = getattr(mesh.devices.flat[0], "platform", "cpu")
+    platform = mesh.devices.flat[0].platform
     f_loc = _make_cov_face_rhs(model, grid, program, overlap, platform)
     frames_z = jnp.asarray(
         np.asarray(FACE_AXES)[:, None, :, 2], jnp.float32)

@@ -37,10 +37,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from ..utils.jax_compat import shard_map
 
 from ..geometry.connectivity import (
     EDGE_E,
@@ -55,6 +53,7 @@ from .halo import read_strip, write_strip
 from .shard_cov import (
     CUBE_ROW_NAMES,
     apply_cov_cube_recv,
+    pallas_interpret_for,
     ssprk3_sharded_body,
 )
 from .shard_halo import _block_coords
@@ -482,14 +481,14 @@ def make_sharded_cov_block_stepper(model, setup, dt: float, overlap=None,
     program = CovBlockProgram(grid, s)
     n_loc = program.n_loc
     exchange = make_cov_block_exchange(program)
-    platform = getattr(mesh.devices.flat[0], "platform", "cpu")
+    interpret = pallas_interpret_for(mesh.devices.flat[0].platform)
 
     from ..ops.pallas.swe_cov import make_cov_rhs_pallas_local
 
     rhs_local = make_cov_rhs_pallas_local(
         n_loc, halo, float(grid.dalpha), float(grid.radius),
         model.gravity, model.omega, scheme=model.scheme,
-        limiter=model.limiter, interpret=(platform != "tpu"),
+        limiter=model.limiter, interpret=interpret,
     )
     if overlap:
         from ..ops.pallas.swe_cov import (make_cov_rhs_band_local,
@@ -499,7 +498,7 @@ def make_sharded_cov_block_stepper(model, setup, dt: float, overlap=None,
         rhs_interior = make_cov_rhs_interior_local(
             n_loc, halo, float(grid.dalpha), float(grid.radius),
             model.gravity, model.omega, scheme=model.scheme,
-            limiter=model.limiter, interpret=(platform != "tpu"))
+            limiter=model.limiter, interpret=interpret)
         rhs_band = make_cov_rhs_band_local(
             n_loc, halo, float(grid.dalpha), float(grid.radius),
             model.gravity, model.omega, scheme=model.scheme,

@@ -22,9 +22,8 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from ..utils.jax_compat import shard_map
 
 from ..geometry.cubed_sphere import CubedSphereGrid
 from ..stepping import SCHEMES

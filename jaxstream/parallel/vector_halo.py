@@ -29,6 +29,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from ..geometry.connectivity import build_connectivity, build_schedule
@@ -61,15 +62,21 @@ def _strip_indices(n: int, halo: int):
     :func:`write_strip` for a canonical strip.
     """
     m = n + 2 * halo
-    flat = np.arange(m * m).reshape(1, m, m)
+
+    @jax.jit
+    def probe(flat, blank, marker):
+        # One compiled program for all 8 probes, not ~20 op-by-op
+        # dispatches that each compile on first use.
+        return ([read_strip(flat, 0, e, halo, n) for e in range(4)],
+                [write_strip(blank, 0, e, marker)[0] for e in range(4)])
+
+    reads, writes = probe(np.arange(m * m).reshape(1, m, m),
+                          np.full((1, m, m), -1),
+                          np.arange(halo * n).reshape(halo, n))
     src_idx, dst_idx = [], []
-    for e in range(4):
-        s = np.asarray(read_strip(jnp.asarray(flat), 0, e, halo, n))
-        src_idx.append(s.reshape(halo * n))
-        marker = jnp.asarray(np.arange(halo * n).reshape(halo, n))
-        out = np.asarray(
-            write_strip(jnp.asarray(np.full((1, m, m), -1)), 0, e, marker)
-        )[0]
+    for s, out in zip(reads, writes):
+        src_idx.append(np.asarray(s).reshape(halo * n))
+        out = np.asarray(out)
         pos = np.argsort(out.ravel())[m * m - halo * n:]  # where out >= 0
         order = out.ravel()[pos]
         dst = np.empty(halo * n, dtype=np.int64)

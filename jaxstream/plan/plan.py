@@ -322,7 +322,7 @@ def plan_for(config, serving: bool = False) -> CapabilityPlan:
                 and m.backend.startswith("pallas")
                 and cfg.time.scheme == "ssprk3"
                 and cfg.physics.hyperdiffusion == 0.0):
-            # Mirror EnsembleServer._impls_for: grouped single-chip
+            # Mirror EnsembleServer._impl_for: grouped single-chip
             # buckets prefer the fused member-fold masked segment.
             tier = "fused"
         if cfg.model.numerics == "tt":

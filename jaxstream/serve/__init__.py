@@ -28,7 +28,7 @@ build on.
 Round 21 adds the warm-pool subsystem (:mod:`jaxstream.serve.
 warmpool`): disk-backed serialized executables keyed by plan + proof +
 toolchain so a restarted server loads instead of recompiling, a
-probe-gated persistent compile cache, speculative compilation of
+persistent compile cache rung, speculative compilation of
 adjacent buckets, and :class:`HeadroomRefused` — the first enforcement
 consumer of the round-19 advisory ``headroom_frac``.
 """

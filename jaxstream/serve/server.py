@@ -9,7 +9,7 @@ axis of the round-7 batched steppers:
 * **Shape-bucketed batching**: batch sizes come from a fixed bucket
   set (``serve.buckets``, default ``1,4,16``) and every bucket's
   masked-segment executable is compiled once and kept warm
-  (``JAXSTREAM_COMPILE_CACHE`` persists even that across restarts), so
+  (the persistent compile cache keeps even that across restarts), so
   steady-state serving triggers ZERO recompiles —
   :meth:`EnsembleServer.compile_count` is the proof surface the tests
   assert on.
@@ -320,7 +320,6 @@ class EnsembleServer:
         self._ics: Dict[str, tuple] = {}
         self._b_zero = None
         self._b_oro = None
-        self._impls: Dict[str, str] = {}
         self._buckets: Dict[tuple, _Bucket] = {}
         self._setups: Dict[tuple, object] = {}
         self._writer: Optional[BackgroundWriter] = None
@@ -410,8 +409,8 @@ class EnsembleServer:
         if fdir:
             self._blackbox = flight.BundleWriter(fdir)
         #: Round 21 (warm pools): the disk-backed executable pool
-        #: (``serve.warm_pool``), the probe-gated persistent compile
-        #: cache (``serve.compile_cache``), and the speculative
+        #: (``serve.warm_pool``), the persistent compile cache rung
+        #: (``serve.compile_cache``), and the speculative
         #: compiler (``serve.speculate``).  The build lock serializes
         #: first-use bucket builds between the serving thread and the
         #: speculator thread; the deployment digest folds the config
@@ -423,7 +422,8 @@ class EnsembleServer:
         self._speculator = None
         #: Entries only persist when ``serve.warm_pool`` names a
         #: directory; a compile-cache-only deployment still gets a pool
-        #: object (it owns the probe verdicts) but load/save stay off.
+        #: object (it owns the compile_cache rung) but load/save stay
+        #: off.
         self._pool_entries = bool(s.warm_pool)
         if s.warm_pool or s.compile_cache:
             pool_dir = s.warm_pool or s.compile_cache + ".pool"
@@ -923,25 +923,24 @@ class EnsembleServer:
                        carry_sh=carry_sh, rep_sh=rep, proof=proof,
                        cost=cost)
 
-    def _impls_for(self, group: str, plan: BucketPlan) -> List[str]:
-        """Candidate stepper impls for one bucket, most preferred
-        first.  Panel-sharded plans run the shard_map ensemble stepper;
-        mixed-orography servers run the traced-b vmapped classic;
-        grouped servers keep the round-11 fused-then-vmap chain
-        (member-sharded plans restrict it to the partitionable vmap —
-        the backend gate in __init__ already enforced jnp)."""
+    def _impl_for(self, group: str, plan: BucketPlan) -> str:
+        """The stepper impl of one bucket.  Panel-sharded plans run the
+        shard_map ensemble stepper; mixed-orography servers run the
+        traced-b vmapped classic; grouped single-chip pallas servers
+        run the fused member-fold — and a build failure there raises
+        instead of dropping to the vmapped classic path (member-
+        sharded plans run the partitionable vmap — the backend gate in
+        __init__ already enforced jnp)."""
         if plan.mode == "panel":
-            return ["panel"]
+            return "panel"
         if not self._grouping:
-            return ["vmap_b"]
-        if group in self._impls:
-            return [self._impls[group]]
+            return "vmap_b"
         cfg = self.config
-        fused_ok = (plan.mode == "single"
-                    and cfg.time.scheme == "ssprk3"
-                    and cfg.model.backend.startswith("pallas")
-                    and cfg.physics.hyperdiffusion == 0.0)
-        return ["fused", "vmap"] if fused_ok else ["vmap"]
+        fused = (plan.mode == "single"
+                 and cfg.time.scheme == "ssprk3"
+                 and cfg.model.backend.startswith("pallas")
+                 and cfg.physics.hyperdiffusion == 0.0)
+        return "fused" if fused else "vmap"
 
     def _bucket(self, group: str, B: int) -> _Bucket:
         """The warm (group, B) runtime — built, compiled and probed on
@@ -961,34 +960,20 @@ class EnsembleServer:
             if bk is not None:      # raced the speculator; it won
                 return bk
             plan = self._plans[B]
-            impls = self._impls_for(group, plan)
-            err = None
-            for impl in impls:
-                try:
-                    bk = self._build_bucket(group, B, impl)
-                    t_warm = time.perf_counter()
-                    self._warm_via_pool(bk)
-                    self._warm_bucket(bk)
-                    bk.cost.compile_seconds = round(
-                        time.perf_counter() - t_warm, 4)
-                    self._stamp_bucket(bk)
-                    self._impls[group] = impl
-                    self._buckets[key] = bk
-                    self.stats["warmup_compiles"] = self.compile_count()
-                    log.info("serve: bucket (%s, B=%d) warm (%s "
-                             "stepper, placement %s x%d)", group, B,
-                             impl, plan.mode, plan.num_devices)
-                    return bk
-                except Exception as e:
-                    err = e
-                    if impl != impls[-1]:
-                        log.warning(
-                            "serve: %s stepper unavailable for bucket "
-                            "(%s, B=%d) (%s: %s); falling back",
-                            impl, group, B, type(e).__name__, e)
-            raise RuntimeError(
-                f"serve: no stepper builds for bucket ({group}, B={B})"
-            ) from err
+            impl = self._impl_for(group, plan)
+            bk = self._build_bucket(group, B, impl)
+            t_warm = time.perf_counter()
+            self._warm_via_pool(bk)
+            self._warm_bucket(bk)
+            bk.cost.compile_seconds = round(
+                time.perf_counter() - t_warm, 4)
+            self._stamp_bucket(bk)
+            self._buckets[key] = bk
+            self.stats["warmup_compiles"] = self.compile_count()
+            log.info("serve: bucket (%s, B=%d) warm (%s stepper, "
+                     "placement %s x%d)", group, B, impl, plan.mode,
+                     plan.num_devices)
+            return bk
 
     def _warm_member_tree(self, group: str):
         family = "tc5" if group == "oro" else "tc2"
@@ -1263,8 +1248,8 @@ class EnsembleServer:
 
     def warmpool_summary(self) -> Optional[dict]:
         """The warm pool's ``/v1/stats`` surface (None = pool off):
-        hit/miss/save/corrupt counters, per-rung hit counts, probe
-        verdicts, and what the speculative compiler built/skipped."""
+        hit/miss/save/corrupt counters, per-rung hit counts, and what
+        the speculative compiler built/skipped."""
         if self._warmpool is None:
             return None
         out = self._warmpool.summary()

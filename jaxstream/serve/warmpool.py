@@ -32,14 +32,12 @@ each rung a typed sink record, never a silent fallback:
 2. ``stablehlo`` — ``jax.export`` StableHLO bytes: a load re-runs the
    backend compile but skips trace + lower.
 3. ``compile_cache`` — jax's persistent compilation cache pointed at
-   ``serve.compile_cache``.  This image's jaxlib (0.4.37) is
-   *documented* to segfault when a different process deserializes CPU
-   cache entries (the ``jax_compat.enable_compile_cache`` quarantine
-   note), so the rung is gated behind a SUBPROCESS feature probe: a
-   child process populates a scratch cache, a second child reloads
-   from it, and only a clean double-exit unlocks the rung in the
-   server process.  The verdict is cached per (jaxlib, backend) so the
-   probe's ~seconds are paid once per pool directory.
+   ``serve.compile_cache``, or kept where ``JAX_COMPILATION_CACHE_DIR``
+   put it when that is set.  (Until PR 21 a subprocess probe gated this
+   rung against an older jaxlib's segfault on cross-process cache
+   reads; on jax 0.9 a cross-process warm run of the TT/serve/async/
+   ensemble test mix read 550 cache entries cleanly, so the probe
+   went.)
 4. ``cold`` — plain jit compile (today's behavior).
 
 **Atomicity.**  Entries commit in the PR-20 flight-recorder style:
@@ -56,15 +54,14 @@ import dataclasses
 import hashlib
 import json
 import os
-import sys
 import threading
-from typing import Callable, Dict, List, Optional
+from typing import Callable, List, Optional
 
 from ..utils import jax_compat
 from ..utils.logging import get_logger
 
 __all__ = ["WarmPool", "WarmExecutable", "HeadroomRefused",
-           "entry_key", "deployment_digest", "probe_rung",
+           "entry_key", "deployment_digest",
            "SpeculativeCompiler", "RUNGS"]
 
 log = get_logger(__name__)
@@ -190,89 +187,6 @@ class WarmExecutable:
         return 0 if inner is None else inner
 
 
-# ------------------------------------------------------ subprocess probe
-#: Child script of one probe arm.  argv: [rung, scratch_dir, phase]
-#: phase 'write' populates (compile + serialize/cache-fill), phase
-#: 'read' consumes what a DIFFERENT process wrote — the exact pattern
-#: the jaxlib-0.4.37 quarantine note says can segfault, which is why
-#: this runs in a child: a SIGSEGV costs an exit code, not the server.
-_PROBE_SCRIPT = r"""
-import os, sys
-rung, scratch, phase = sys.argv[1], sys.argv[2], sys.argv[3]
-os.environ.setdefault("JAX_PLATFORMS", os.environ.get(
-    "JAXSTREAM_PROBE_PLATFORM", "cpu"))
-import jax, jax.numpy as jnp
-from jaxstream.utils import jax_compat
-fn = jax.jit(lambda x: x * 2.0 + 1.0)
-x = jnp.arange(8.0)
-payload_path = os.path.join(scratch, "probe.bin")
-if rung == "aot":
-    if phase == "write":
-        blob = jax_compat.serialize_executable(
-            fn.lower(x).compile())
-        with open(payload_path, "wb") as fh:
-            fh.write(blob)
-    else:
-        with open(payload_path, "rb") as fh:
-            blob = fh.read()
-        loaded = jax_compat.deserialize_executable(blob)
-        out = loaded(x)
-        assert float(out[1]) == 3.0, out
-elif rung == "compile_cache":
-    jax_compat.enable_compile_cache(os.path.join(scratch, "cache"))
-    out = fn(x)
-    jax.block_until_ready(out)
-    assert float(out[1]) == 3.0, out
-    if phase == "write":
-        entries = os.listdir(os.path.join(scratch, "cache"))
-        assert entries, "compile cache stayed empty"
-else:
-    raise SystemExit(f"unknown probe rung {rung!r}")
-"""
-
-
-def probe_rung(rung: str, scratch_dir: str,
-               timeout: float = 120.0) -> dict:
-    """Cross-process feature probe of one warm-pool rung.
-
-    Runs TWO child processes: a writer that compiles and persists (a
-    serialized executable, or a populated compile cache), then a
-    reader that consumes the writer's on-disk artifact — the
-    cross-process deserialization this image's jaxlib is documented to
-    segfault on for CPU compile-cache entries.  Returns a verdict dict
-    ``{"rung", "ok", "detail"}``; a crash (any nonzero exit, including
-    a signal) is a typed ``ok: False``, never an exception — the pool
-    records the verdict and degrades a rung.
-    """
-    import subprocess
-
-    if rung not in ("aot", "compile_cache"):
-        raise ValueError(f"unprobed rung {rung!r}; probe covers "
-                         "('aot', 'compile_cache')")
-    os.makedirs(scratch_dir, exist_ok=True)
-    env = dict(os.environ)
-    # The probe must see the same platform the server runs, but never
-    # inherit a live compile-cache env var that would alias scratch.
-    env.pop("JAXSTREAM_COMPILE_CACHE", None)
-    for phase in ("write", "read"):
-        try:
-            res = subprocess.run(
-                [sys.executable, "-c", _PROBE_SCRIPT, rung,
-                 scratch_dir, phase],
-                capture_output=True, text=True, timeout=timeout,
-                env=env)
-        except subprocess.TimeoutExpired:
-            return {"rung": rung, "ok": False,
-                    "detail": f"{phase} probe timed out at {timeout}s"}
-        if res.returncode != 0:
-            tail = (res.stderr or res.stdout or "").strip()[-300:]
-            return {"rung": rung, "ok": False,
-                    "detail": (f"{phase} probe exited "
-                               f"{res.returncode}: {tail}")}
-    return {"rung": rung, "ok": True,
-            "detail": "cross-process write+read probes exited clean"}
-
-
 # --------------------------------------------------------------- the pool
 @dataclasses.dataclass
 class _Entry:
@@ -286,10 +200,10 @@ class _Entry:
 
 
 class WarmPool:
-    """One directory of serialized bucket executables + rung probes.
+    """One directory of serialized bucket executables.
 
     ``sink_write`` receives the typed ``warmpool`` records (hit / miss
-    / save / corrupt / probe / fallback — never a silent rung change);
+    / save / corrupt / fallback — never a silent rung change);
     ``counter_inc`` is the metrics hook (``jaxstream_warmpool_*`` on
     ``/v1/metrics``).  Thread-safe: the speculative compiler and the
     serving thread share one pool under ``self._lock``.
@@ -297,18 +211,13 @@ class WarmPool:
 
     def __init__(self, path: str, compile_cache: str = "",
                  sink_write: Optional[Callable] = None,
-                 counter_inc: Optional[Callable] = None,
-                 environment: Optional[dict] = None,
-                 probe: Optional[Callable] = None):
+                 counter_inc: Optional[Callable] = None):
         self.path = path
         os.makedirs(path, exist_ok=True)
         self.compile_cache = compile_cache
         self._sink_write = sink_write
         self._counter_inc = counter_inc
-        self._environment = environment
-        self._probe = probe if probe is not None else probe_rung
         self._lock = threading.Lock()
-        self._verdicts: Dict[str, dict] = {}
         self.stats = {"hits": 0, "misses": 0, "saves": 0,
                       "corrupt": 0, "rungs": {}}
         self._cache_enabled = False
@@ -352,65 +261,21 @@ class WarmPool:
 
         _atomic_write_bytes(path, data)
 
-    # ------------------------------------------------------------ probing
-    def rung_verdict(self, rung: str) -> dict:
-        """The (cached) cross-process probe verdict of one rung.
-
-        Cached two ways: in-process per pool, and on disk next to the
-        entries keyed by (jaxlib, backend) — a fleet of servers
-        sharing one pool directory pays the probe's seconds once.  The
-        verdict lands in the sink as a typed ``probe`` record either
-        way, so every deployment's telemetry says which rungs were
-        trusted and why.
-        """
-        if rung in self._verdicts:
-            return self._verdicts[rung]
-        env = (self._environment if self._environment is not None
-               else _environment_fields())
-        tag = hashlib.sha256(json.dumps(
-            {"rung": rung, "jaxlib": env.get("jaxlib"),
-             "backend": env.get("backend")},
-            sort_keys=True).encode()).hexdigest()[:16]
-        vpath = os.path.join(self.path, f"probe_{rung}_{tag}.json")
-        verdict = None
-        if os.path.exists(vpath):
-            try:
-                with open(vpath) as fh:
-                    verdict = json.load(fh)
-                if verdict.get("rung") != rung:
-                    verdict = None
-            except Exception:
-                verdict = None
-        cached = verdict is not None
-        if verdict is None:
-            verdict = self._probe(
-                rung, os.path.join(self.path, f"_probe_{rung}"))
-            try:
-                self._atomic_write(
-                    vpath, json.dumps(verdict).encode())
-            except OSError as e:
-                log.warning("warmpool: probe verdict not cached "
-                            "(%s: %s)", type(e).__name__, e)
-        self._verdicts[rung] = verdict
-        self._record("probe", rung, None, ok=bool(verdict.get("ok")),
-                     detail=str(verdict.get("detail", "")),
-                     cached=cached)
-        return verdict
-
+    # ------------------------------------------------- compile cache rung
     def enable_compile_cache(self) -> bool:
-        """Engage the ``compile_cache`` rung iff configured AND the
-        subprocess probe proved cross-process deserialization safe on
-        this toolchain.  Idempotent; returns whether the cache is on."""
+        """Engage the ``compile_cache`` rung iff configured; returns
+        whether the cache is on.  A ``JAX_COMPILATION_CACHE_DIR`` set
+        from outside wins: the cache stays in that directory and
+        ``serve.compile_cache`` only switches the rung on.  Idempotent.
+        """
         if self._cache_enabled:
             return True
         if not self.compile_cache:
             return False
-        verdict = self.rung_verdict("compile_cache")
-        if not verdict.get("ok"):
-            self._record("fallback", "compile_cache", None,
-                         reason=str(verdict.get("detail", "")))
-            return False
-        jax_compat.enable_compile_cache(self.compile_cache)
+        path = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                or self.compile_cache)
+        jax_compat.enable_compile_cache(path)
+        self.compile_cache = path
         self._cache_enabled = True
         return True
 
@@ -498,12 +363,8 @@ class WarmPool:
     def _save_locked(self, key, jitted, compiled, example_args,
                      plan_key, donate):
         payload = rung = None
-        # The aot/stablehlo rungs gate on API availability alone: their
-        # loads were verified safe on this toolchain (and a corrupt
-        # payload degrades through the typed torn-entry path anyway).
-        # Only the compile_cache rung carries the documented
-        # cross-process segfault class, so only it pays the subprocess
-        # probe (jax_compat.enable_compile_cache quarantine note).
+        # The aot/stablehlo rungs gate on API availability alone (a
+        # corrupt payload degrades through the typed torn-entry path).
         if jax_compat.executable_serialization_available():
             try:
                 payload = jax_compat.serialize_executable(compiled)
@@ -525,7 +386,7 @@ class WarmPool:
                              reason=str(e))
         if payload is None:
             # Last resort below cold: the persistent compile cache
-            # (probe-gated) at least makes the next cold compile warm.
+            # at least makes the next cold compile warm.
             self.enable_compile_cache()
             return None
         meta = {"key": key, "rung": rung,
@@ -550,7 +411,7 @@ class WarmPool:
         return rung
 
     def summary(self) -> dict:
-        """The ``/v1/stats`` payload: counters + probed verdicts."""
+        """The ``/v1/stats`` payload: counters per rung."""
         return {
             "path": self.path,
             "compile_cache": (self.compile_cache
@@ -560,9 +421,6 @@ class WarmPool:
             "saves": self.stats["saves"],
             "corrupt": self.stats["corrupt"],
             "rungs": dict(self.stats["rungs"]),
-            "probes": {r: {"ok": v.get("ok"),
-                           "detail": v.get("detail")}
-                       for r, v in sorted(self._verdicts.items())},
         }
 
 

@@ -217,100 +217,76 @@ class Simulation:
                 and hasattr(m, "ensemble_compact_state")):
             # Batched ensemble fast path: the member axis folds into the
             # stage kernels' grid, so all B members ride one kernel
-            # launch per stage (jaxstream.ops.pallas.swe_cov).
-            try:
-                self._fused_step = m.make_fused_step(
-                    cfg.time.dt, temporal_block=tb, ensemble=self.members,
-                    **pkw)
-                if p_enc is not None:
-                    # Strip narrowing only (carry encodings are
-                    # rejected for ensembles in _resolve_precision).
-                    self._fused_prep = (
-                        lambda s, _e=p_enc: _e(m.ensemble_compact_state(s)))
-                else:
-                    self._fused_prep = m.ensemble_compact_state
-                log.info("using batched ensemble fused SSPRK3 stepper "
-                         "(%d members per kernel launch)", self.members)
-            except Exception as e:
-                if pkw:
-                    raise ValueError(
-                        "precision: block configured but the batched "
-                        f"fused stepper failed to build ({type(e).__name__}"
-                        f": {e}); the policy has no classic-path form, so "
-                        "refusing to silently run f32") from e
-                log.warning(
-                    "batched fused stepper unavailable (%s: %s); falling "
-                    "back to the vmapped classic path",
-                    type(e).__name__, e,
-                )
+            # launch per stage (jaxstream.ops.pallas.swe_cov).  A build
+            # failure raises: a pallas run never silently drops to the
+            # vmapped classic path.
+            self._fused_step = m.make_fused_step(
+                cfg.time.dt, temporal_block=tb, ensemble=self.members,
+                **pkw)
+            if p_enc is not None:
+                # Strip narrowing only (carry encodings are
+                # rejected for ensembles in _resolve_precision).
+                self._fused_prep = (
+                    lambda s, _e=p_enc: _e(m.ensemble_compact_state(s)))
+            else:
+                self._fused_prep = m.ensemble_compact_state
+            log.info("using batched ensemble fused SSPRK3 stepper "
+                     "(%d members per kernel launch)", self.members)
         elif (self.members == 1 and self.setup is None
                 and cfg.time.scheme == "ssprk3"
                 and getattr(m, "backend", "").startswith("pallas")
                 and (getattr(m, "nu4", 0.0) == 0.0
                      or getattr(m, "fused_supports_nu4", False))
                 and hasattr(m, "make_fused_step")):
-            try:
-                # The stepper and its carry-prep are a matched pair: pick
-                # both here so they cannot drift apart.
-                def _mk_fused():
-                    """Fused step honoring temporal_block where the
-                    model knows the knob (covariant multistep factory);
-                    exact k-step fusion via stepping.blocked otherwise."""
-                    try:
-                        return m.make_fused_step(cfg.time.dt,
-                                                 temporal_block=tb, **pkw)
-                    except TypeError:
-                        if pkw:
-                            # The precision/nu4_mode kwargs have no
-                            # generic fallback — a model that doesn't
-                            # know them can't honor the config.
-                            raise
-                        step = m.make_fused_step(cfg.time.dt)
-                        if tb > 1:
-                            from .stepping import blocked
+            # The stepper and its carry-prep are a matched pair: pick
+            # both here so they cannot drift apart.
+            def _mk_fused():
+                """Fused step honoring temporal_block where the
+                model knows the knob (covariant multistep factory);
+                exact k-step fusion via stepping.blocked otherwise."""
+                try:
+                    return m.make_fused_step(cfg.time.dt,
+                                             temporal_block=tb, **pkw)
+                except TypeError:
+                    if pkw:
+                        # The precision/nu4_mode kwargs have no
+                        # generic fallback — a model that doesn't
+                        # know them can't honor the config.
+                        raise
+                    step = m.make_fused_step(cfg.time.dt)
+                    if tb > 1:
+                        from .stepping import blocked
 
-                            step = blocked(step, tb, cfg.time.dt)
-                            step.steps_per_call = tb
-                        return step
+                        step = blocked(step, tb, cfg.time.dt)
+                        step.steps_per_call = tb
+                    return step
 
-                if hasattr(m, "compact_state"):
-                    self._fused_step = _mk_fused()
-                    if p_enc is not None:
-                        self._fused_prep = (
-                            lambda s, _e=p_enc: _e(m.compact_state(s)))
-                        self._fused_post = p_dec
-                    else:
-                        self._fused_prep = m.compact_state
-                    log.info("using compact fused SSPRK3 stepper "
-                             "(interior-only carry)")
+            if hasattr(m, "compact_state"):
+                self._fused_step = _mk_fused()
+                if p_enc is not None:
+                    self._fused_prep = (
+                        lambda s, _e=p_enc: _e(m.compact_state(s)))
+                    self._fused_post = p_dec
                 else:
-                    if pkw.get("precision") or p_enc is not None:
-                        raise ValueError(
-                            "precision: block needs the compact-carry "
-                            "fused stepper (this model only has the "
-                            "extended-state form)")
-                    self._fused_step = _mk_fused()
-                    self._fused_prep = functools.partial(
-                        m.extend_state, with_strips=True)
-                    log.info("using fused extended-state SSPRK3 stepper")
-            except Exception as e:
-                if pkw or p_enc is not None:
+                    self._fused_prep = m.compact_state
+                log.info("using compact fused SSPRK3 stepper "
+                         "(interior-only carry)")
+            else:
+                if pkw.get("precision") or p_enc is not None:
                     raise ValueError(
-                        "precision: block configured but the fused "
-                        f"stepper failed to build ({type(e).__name__}: "
-                        f"{e}); the policy has no classic-path form, so "
-                        "refusing to silently run f32") from e
-                log.warning(
-                    "fused stepper unavailable (%s: %s); falling back to "
-                    "the classic path (~2x slower on TPU)",
-                    type(e).__name__, e,
-                )
+                        "precision: block needs the compact-carry "
+                        "fused stepper (this model only has the "
+                        "extended-state form)")
+                self._fused_step = _mk_fused()
+                self._fused_prep = functools.partial(
+                    m.extend_state, with_strips=True)
+                log.info("using fused extended-state SSPRK3 stepper")
         if (pkw or p_enc is not None) and self._fused_step is None:
             plan_rules.fail("precision-needs-fused-path")
         # The run's proof stamp: rules verdict + schedule fingerprint +
         # enumerated-matrix coverage for the stepper that will actually
-        # execute (the fused gate above may have fallen back to the
-        # classic path — re-resolve the tier so the stamp is honest).
+        # execute (re-resolve the tier where the gate above picked no
+        # fused stepper, so the stamp is honest).
         actual = self.plan
         if actual.tier == "fused" and self._fused_step is None:
             import dataclasses as _dc

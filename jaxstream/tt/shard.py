@@ -41,10 +41,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ..utils.jax_compat import shard_map
 
 from ..parallel.halo import EDGE_E, EDGE_N, EDGE_S, EDGE_W
 from ..parallel.shard_halo import ShardHaloProgram

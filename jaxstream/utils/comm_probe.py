@@ -274,7 +274,7 @@ def probe_exchange(grid, mesh, iters: int = 100):
 
     from ..parallel.shard_cov import (CovShardProgram,
                                       make_cov_shard_exchange)
-    from .jax_compat import shard_map
+    from jax import shard_map
 
     n, halo, m = grid.n, grid.halo, grid.m
     program = CovShardProgram(grid)

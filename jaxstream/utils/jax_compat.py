@@ -1,73 +1,39 @@
-"""Version-portability shims for the narrow band of JAX APIs we use.
+"""Thin wrappers over the narrow band of JAX APIs we use.
 
-The repo targets current JAX (``jax.shard_map``, ``pltpu.CompilerParams``)
-but must also run on the 0.4.x line some images ship, where those spell
-``jax.experimental.shard_map.shard_map(..., check_rep=...)`` and
-``pltpu.TPUCompilerParams``.  Every call site routes through this module
-so the rest of the codebase is written against ONE (the current) API.
-
-Only strictly-renamed APIs belong here — behavioral divergences must be
-handled (and documented) at the call site.
+The repo targets one installation: jax/jaxlib 0.9.  Every wrapper here
+exists to turn a missing or failing JAX surface into a typed
+``RuntimeError`` (or a documented None) instead of AttributeError soup,
+or to hold the one rule for a process-wide JAX setting (the compile
+cache).
 """
 
 from __future__ import annotations
 
-import contextlib
 import os
 
 import jax
 
-__all__ = ["LEGACY_SHARD_MAP", "compile_count", "copy_to_host_async",
+__all__ = ["compile_count", "copy_to_host_async",
+           "default_compile_cache_dir",
            "deserialize_executable", "deserialize_stablehlo",
            "device_memory_stats", "enable_compile_cache",
            "executable_serialization_available",
-           "maybe_enable_compile_cache", "memory_analysis",
-           "named_scope", "profiler_available", "serialize_executable",
-           "serialize_stablehlo", "shard_map",
-           "stablehlo_serialization_available", "start_profiler_trace",
-           "stop_profiler_trace", "tpu_compiler_params"]
-
-#: True on the 0.4.x line.  Besides the spelling differences shimmed
-#: below, that line's XLA trips an hlo-verifier bug ("tile_assignment
-#: should have N devices") on ``vmap(while)`` bodies inside shard_map —
-#: loops that can be statically unrolled should be when this is set
-#: (see cross.aca_lowrank).
-LEGACY_SHARD_MAP = not hasattr(jax, "shard_map")
-
-
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # jax < 0.5: experimental namespace, check_vma spelled check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f=None, /, *, mesh, in_specs, out_specs,
-                  check_vma: bool = True, **kw):
-        """``jax.shard_map`` signature adapter over the experimental API.
-
-        ``check_vma`` (varying-manual-axes checking) is the renamed
-        ``check_rep``; axis semantics are identical for the SPMD
-        programs this repo builds (no auto axes used).
-        """
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma,
-                              **kw)
+           "memory_analysis", "named_scope", "place_compile_cache",
+           "profiler_available", "serialize_executable",
+           "serialize_stablehlo", "stablehlo_serialization_available",
+           "start_profiler_trace", "stop_profiler_trace"]
 
 
 def named_scope(name: str):
-    """``jax.named_scope`` under any supported version; inert otherwise.
+    """``jax.named_scope``: a trace-time name-stack annotation.
 
-    A trace-time name-stack annotation: every op traced inside the
-    context carries ``name`` as a prefix, so xprof/TensorBoard traces
-    show the fused steppers as named regions (exchange start/finish,
-    interior vs band RHS, RK stages, TT sweeps) instead of anonymous
-    custom-call soup.  Zero runtime cost — the name lives in HLO
-    metadata only — and a no-op context if the API is ever absent, so
-    annotated code never gains a hard version dependency.
+    Every op traced inside the context carries ``name`` as a prefix, so
+    xprof/TensorBoard traces show the fused steppers as named regions
+    (exchange start/finish, interior vs band RHS, RK stages, TT sweeps)
+    instead of anonymous custom-call soup.  Zero runtime cost — the
+    name lives in HLO metadata only.
     """
-    ns = getattr(jax, "named_scope", None)
-    if ns is None:  # pragma: no cover - every supported jax has it
-        return contextlib.nullcontext()
-    return ns(name)
+    return jax.named_scope(name)
 
 
 def profiler_available() -> bool:
@@ -228,50 +194,56 @@ def copy_to_host_async(tree):
     return jax.tree_util.tree_map(start, tree)
 
 
-def enable_compile_cache(path: str) -> str:
-    """Point jax's persistent compilation cache at ``path`` (created).
+def default_compile_cache_dir() -> str:
+    """The fixed in-checkout cache directory: ``<repo>/.jax_cache``.
 
-    Also zeroes ``jax_persistent_cache_min_compile_time_secs`` so every
-    executable is cached — the fast tier's compiles are individually
-    sub-second but collectively dominate its wall time.  KNOWN LIMIT on
-    this image's jaxlib (0.4.37): a *different process* deserializing
-    CPU cache entries segfaults (tests/conftest.py round-8 note), so
-    cross-process reuse is an opt-in via ``JAXSTREAM_COMPILE_CACHE``
-    rather than a default; same-process reuse (``jax.clear_caches()``
-    then recompile, what ``bench.py --compile-report`` measures) is
-    solid.
+    Fixed on purpose — the directory is part of what a process must
+    find again, so it is never derived from a temp name, a pid or the
+    time.  ``.gitignore`` lists it.
     """
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    for flag, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(flag, val)
-        except Exception:  # flag spelling drifts across jax versions
-            pass
-    try:
-        # jax latches cache-enablement once per process at the first
-        # compile (is_cache_used's _cache_checked); enabling the cache
-        # AFTER something already compiled needs the latch reset or the
-        # directory silently stays empty.
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(repo, ".jax_cache")
 
-        _cc.reset_cache()
-    except Exception:
-        pass
+
+def place_compile_cache() -> str:
+    """Place jax's persistent compilation cache; returns the directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set (or a directory already set in
+    this process): jax uses it, and nothing is set in code.  Otherwise
+    the cache goes to :func:`default_compile_cache_dir`.  Only the
+    directory is set — whether the cache is used stays jax's own
+    ``jax_enable_compilation_cache`` (the tier-1 suite turns it off in
+    ``tests/conftest.py``).  Called on package import, so every entry
+    point (``Simulation``, the CLI, ``chip_smoke.py``) shares one
+    cache.
+    """
+    path = jax.config.jax_compilation_cache_dir
+    if not path:
+        path = default_compile_cache_dir()
+        jax.config.update("jax_compilation_cache_dir", path)
     return path
 
 
-def maybe_enable_compile_cache(env: str = "JAXSTREAM_COMPILE_CACHE"):
-    """Enable the persistent compile cache iff ``$JAXSTREAM_COMPILE_CACHE``
-    names a directory; returns the path or None.  Called on package
-    import (jaxstream/__init__.py) so any entrypoint — Simulation, the
-    CLI, bench.py — picks the cache up from the environment alone."""
-    path = os.environ.get(env, "")
-    if not path:
-        return None
-    return enable_compile_cache(path)
+def enable_compile_cache(path: str) -> str:
+    """Point jax's persistent compilation cache at ``path`` (created)
+    and cache every executable, however quick its compile.
+
+    The ``serve.compile_cache`` rung and ``bench.py --compile-report``
+    call this; both leave a ``JAX_COMPILATION_CACHE_DIR`` set from
+    outside in place (:meth:`jaxstream.serve.warmpool.WarmPool.
+    enable_compile_cache`).  jax latches cache use once per process at
+    the first compile, so the latch is reset here or a cache enabled
+    after a compile stays empty.
+    """
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.makedirs(path, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    compilation_cache.reset_cache()
+    return path
 
 
 # ----------------------------------------------- executable serialization
@@ -299,9 +271,10 @@ def serialize_executable(compiled) -> bytes:
 
     ``jax.experimental.serialize_executable.serialize`` returns
     ``(unloaded_bytes, in_tree, out_tree)``; the pytree defs are part
-    of the call contract, so the three are pickled together as ONE
-    opaque payload ``deserialize_executable`` reverses.  Raises the
-    typed RuntimeError on builds without the API.
+    of the call contract, so the three are pickled together with the
+    platform and the ids of the devices the executable was compiled
+    for — ONE opaque payload ``deserialize_executable`` reverses.
+    Raises the typed RuntimeError on builds without the API.
     """
     if not executable_serialization_available():
         raise RuntimeError(
@@ -312,7 +285,10 @@ def serialize_executable(compiled) -> bytes:
     from jax.experimental import serialize_executable as se
 
     try:
-        return pickle.dumps(se.serialize(compiled))
+        devices = compiled.runtime_executable().local_devices()
+        return pickle.dumps((se.serialize(compiled),
+                             devices[0].platform,
+                             [d.id for d in devices]))
     except Exception as e:
         raise RuntimeError(
             f"unavailable: executable serialization failed "
@@ -322,9 +298,13 @@ def serialize_executable(compiled) -> bytes:
 def deserialize_executable(payload: bytes):
     """Bytes from :func:`serialize_executable` -> a loaded, callable
     ``Compiled`` — ZERO XLA compiles (the warm pool's zero-compile
-    parity gate reads exactly this property).  The payload must come
-    from the same jaxlib/backend/device-count — the warm-pool cache
-    key enforces that; this function only reverses the encoding."""
+    parity gate reads exactly this property).
+
+    The executable is loaded onto the devices it was compiled for: on
+    jax 0.9 ``deserialize_and_load`` otherwise loads onto EVERY device
+    of the backend and the first call fails its shard count.  The
+    payload must come from the same jaxlib/backend/device-count — the
+    warm-pool cache key enforces that."""
     if not executable_serialization_available():
         raise RuntimeError(
             "unavailable: this jax build exposes no "
@@ -334,7 +314,11 @@ def deserialize_executable(payload: bytes):
     from jax.experimental import serialize_executable as se
 
     try:
-        return se.deserialize_and_load(*pickle.loads(payload))
+        (blob, in_tree, out_tree), platform, ids = pickle.loads(payload)
+        by_id = {d.id: d for d in jax.devices(platform)}
+        return se.deserialize_and_load(
+            blob, in_tree, out_tree, backend=platform,
+            execution_devices=[by_id[i] for i in ids])
     except Exception as e:
         raise RuntimeError(
             f"unavailable: executable deserialization failed "
@@ -390,13 +374,3 @@ def deserialize_stablehlo(payload: bytes, donate_argnums=()):
         raise RuntimeError(
             f"unavailable: StableHLO import failed "
             f"({type(e).__name__}: {e})")
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams(**kwargs)`` under either spelling."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)

@@ -29,7 +29,8 @@ import jax
 
 __all__ = [
     "HardwareRoof", "TPU_V4_CLASS", "TPU_V5E", "TPU_V5P",
-    "TPU_V5E_VPU", "TPU_V5E_VPU_BF16", "mixed_vpu_roof",
+    "TPU_V5E_VPU", "TPU_V5E_VPU_BF16", "DEVICE_ROOFS", "device_roofs",
+    "mixed_vpu_roof",
     "cost_analysis", "analytic_cov_step_cost", "roofline", "Roofline",
     "StepTimer", "median_chain_seconds", "steady_state_rate", "trace",
 ]
@@ -69,6 +70,36 @@ TPU_V5P_VPU = HardwareRoof("TPU v5p VPU f32 stencil-mix", 2765.0, 5.5)
 # roofs; mixed_vpu_roof() computes the harmonic blend for a given bf16
 # flop fraction.
 TPU_V5E_VPU_BF16 = HardwareRoof("TPU v5e VPU bf16 stencil-mix", 819.0, 5.2)
+
+
+#: Peaks of each chip the repo has a roof for, keyed by jax's
+#: ``device.device_kind``.  Source of the HBM and bf16 MXU numbers:
+#: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM
+#: at 819 GB/s per chip).  The VPU stencil-mix roofs are this repo's
+#: own estimate (comment above).  A kind missing here is an error,
+#: never v5e: see :func:`device_roofs`.
+DEVICE_ROOFS = {
+    "TPU v5 lite": {"mxu": TPU_V5E, "vpu": TPU_V5E_VPU,
+                    "vpu_bf16": TPU_V5E_VPU_BF16},
+}
+
+
+def device_roofs(device_kind: str):
+    """The roofs of one device kind, or None for a CPU run.
+
+    A CPU run gets no roofline share (its numbers are not a device's);
+    an accelerator whose kind is not in :data:`DEVICE_ROOFS` raises —
+    dividing by another chip's peaks would print a wrong share.
+    """
+    if device_kind == "cpu":
+        return None
+    try:
+        return DEVICE_ROOFS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no roofline peaks for device kind {device_kind!r}; add "
+            "them to jaxstream.utils.profiling.DEVICE_ROOFS with their "
+            f"source (known: {sorted(DEVICE_ROOFS)})") from None
 
 
 def mixed_vpu_roof(bf16_fraction: float,

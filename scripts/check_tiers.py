@@ -155,24 +155,16 @@ every gate run self-checking):
     and waits on it, which is exactly the cost profile the fast
     tier's budget excludes.
 
-15. **Warm-pool tests stay non-slow and in-process; cross-process
-    cache-deserialization tests stay slow** (round-21 compile-tax
-    satellite).  Two halves: (a) a test module importing the warm-pool
+15. **Warm-pool tests stay non-slow and in-process** (round-21
+    compile-tax satellite): a test module importing the warm-pool
     surface (``jaxstream.serve.warmpool``) must carry NO ``slow``
     markers and must not launch subprocesses — the cache-key
     invalidation proofs (rules-version bump / plan / toolchain string
     MISS, never a stale hit), the torn-entry detection and the
-    zero-warm-compile restart claim are tier-1 acceptance criteria;
-    drive the rung probe through the pool's injectable ``probe=``
-    fake, never a real child process; (b) any test module that
-    launches subprocesses AND references the cross-process compile-
-    cache surface (``enable_compile_cache`` / ``probe_rung`` /
-    ``JAXSTREAM_COMPILE_CACHE``) must carry ``pytest.mark.slow`` —
-    cross-process CPU cache deserialization is the documented
-    jaxlib-0.4.37 segfault class the subprocess probe exists to
-    quarantine, and a real two-process probe costs tens of seconds of
-    child jax imports, which is exactly the cost profile the fast
-    tier's budget excludes.
+    zero-warm-compile restart claim are tier-1 acceptance criteria.
+    (PR 21 dropped the rule's second half, which forced cross-process
+    compile-cache tests slow against an older jaxlib's segfault: jax
+    0.9 reads another process's CPU cache entries cleanly.)
 
 Exit status 0 = clean; 1 = violations (listed on stdout).
 """
@@ -274,13 +266,6 @@ _WARMPOOL_IMPORT_RE = re.compile(
     r"\b(warmpool|WarmPool|WarmExecutable|HeadroomRefused"
     r"|SpeculativeCompiler)\b)",
     re.MULTILINE)
-#: The cross-process compile-cache surface: a subprocess-launching
-#: test referencing any of these is exercising the documented
-#: jaxlib-0.4.37 cache-deserialization segfault class and must ride
-#: the slow tier (rule 15b).
-_CACHE_XPROC_RE = re.compile(
-    r"\benable_compile_cache\b|\bprobe_rung\b"
-    r"|JAXSTREAM_COMPILE_CACHE|jax\.config.*compilation_cache")
 #: A hard-kill reference next to subprocess usage marks the SIGKILL
 #: crash-forensics capstone (and anything shaped like it) — those
 #: must ride the slow tier.
@@ -578,25 +563,9 @@ def lint_file(path: str, allowed: set):
         if _SUBPROC_USE_RE.search(src):
             yield (f"{rel}: imports the warm-pool surface but "
                    f"launches subprocesses — warm-pool tests must run "
-                   f"IN-PROCESS (drive the rung probe through the "
-                   f"pool's injectable probe= fake; a real "
-                   f"two-process probe imports jax in a child and "
-                   f"would be forced slow by rule 2, dropping the "
-                   f"compile-tax proofs from the fast gate); "
-                   f"cross-process cache-deserialization tests live "
-                   f"in a slow-marked module that does not import "
-                   f"the surface (rule 15b)")
-    if _SUBPROC_USE_RE.search(src) and _CACHE_XPROC_RE.search(src) \
-            and "slow" not in used:
-        yield (f"{rel}: launches subprocesses and references the "
-               f"cross-process compile-cache surface "
-               f"(enable_compile_cache / probe_rung / "
-               f"JAXSTREAM_COMPILE_CACHE) but carries no "
-               f"pytest.mark.slow — cross-process CPU cache "
-               f"deserialization is the documented jaxlib "
-               f"segfault class the subprocess probe quarantines, "
-               f"and a real child-process jax import is exactly the "
-               f"cost profile the fast tier's budget excludes")
+                   f"IN-PROCESS (a child that imports jax would be "
+                   f"forced slow by rule 2, dropping the compile-tax "
+                   f"proofs from the fast gate)")
     if _SUBPROC_USE_RE.search(src) and _HARD_KILL_RE.search(src) \
             and "slow" not in used:
         yield (f"{rel}: launches subprocesses and references a hard "

@@ -102,6 +102,9 @@ def main():
         try:
             rl = obs_perf.roofline_json(rate, n,
                                         carry_bytes=carry_bytes)
+            if rl is None:
+                print("    roofline: none on cpu")
+                continue
             print(f"    roofline: {rl['achieved_tflops']} TFLOP/s "
                   f"({rl['pct_of_compute_roof']}% of VPU roof), "
                   f"{rl['achieved_gbps']} GB/s "

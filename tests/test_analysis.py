@@ -111,7 +111,7 @@ def test_traced_broken_schedule_changes_fingerprint():
     from jax.sharding import Mesh, PartitionSpec as P
 
     from jaxstream.analysis.jaxpr_audit import audit_rounds, trace
-    from jaxstream.utils.jax_compat import shard_map
+    from jax import shard_map
 
     perms, _ = fixtures.broken_dropped_pair_perms()
     mesh = Mesh(jax.devices("cpu")[:6], ("panel",))

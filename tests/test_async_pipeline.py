@@ -347,39 +347,63 @@ def test_segment_records_carry_host_wait(tmp_path):
         assert s["host_wait_s"] >= 0.0
 
 
-# -------------------------------------------------- compile-cache opt-in
-def test_compile_cache_env_hook_writes_and_reloads(tmp_path):
-    """JAXSTREAM_COMPILE_CACHE satellite: enabling the persistent cache
-    populates the directory, and a same-process clear_caches+recompile
-    round trip still works (cross-PROCESS reuse is the documented
-    jaxlib-0.4.37 CPU hazard, so this test never spawns one)."""
+# ------------------------------------------------------- compile cache
+_CACHE_FLAGS = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+                "jax_persistent_cache_min_compile_time_secs",
+                "jax_persistent_cache_min_entry_size_bytes")
+
+
+@pytest.fixture
+def cache_config():
+    """Restore jax's compile-cache settings (and the use latch) after
+    a test moves them; the suite itself runs with the cache off."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    saved = {n: getattr(jax.config, n) for n in _CACHE_FLAGS}
+    yield
+    for n, v in saved.items():
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+    jax.clear_caches()
+
+
+def test_compile_cache_writes_and_reloads(tmp_path, cache_config):
+    """With the cache on, ``enable_compile_cache`` populates the
+    directory, and a clear_caches+recompile round trip still works."""
     import jax.numpy as jnp
 
     from jaxstream.utils.jax_compat import enable_compile_cache
 
     d = str(tmp_path / "cc")
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        enable_compile_cache(d)
-        fn = jax.jit(lambda x: jnp.sin(x) * 2.0 + jnp.cos(x))
-        x = jnp.arange(128.0)
-        fn.lower(x).compile()
-        assert os.listdir(d), "no persistent cache entries written"
-        jax.clear_caches()
-        np.testing.assert_allclose(
-            np.asarray(fn(x)),
-            np.sin(np.arange(128.0)) * 2.0 + np.cos(np.arange(128.0)),
-            rtol=1e-6)
-    finally:
-        # Restore the PREVIOUS cache dir rather than hardcoding None,
-        # so this test stays correct if the harness ever runs with a
-        # cache configured.
-        jax.config.update("jax_compilation_cache_dir", prev)
-        try:
-            from jax.experimental.compilation_cache import (
-                compilation_cache as cc)
+    jax.config.update("jax_enable_compilation_cache", True)
+    enable_compile_cache(d)
+    fn = jax.jit(lambda x: jnp.sin(x) * 2.0 + jnp.cos(x))
+    x = jnp.arange(128.0)
+    fn.lower(x).compile()
+    assert os.listdir(d), "no persistent cache entries written"
+    jax.clear_caches()
+    np.testing.assert_allclose(
+        np.asarray(fn(x)),
+        np.sin(np.arange(128.0)) * 2.0 + np.cos(np.arange(128.0)),
+        rtol=1e-6)
 
-            cc.reset_cache()            # drop the enablement latch too
-        except Exception:
-            pass
-        jax.clear_caches()
+
+@pytest.mark.parametrize("outside", [False, True])
+def test_compile_cache_placement(tmp_path, cache_config, outside):
+    """The package places the cache: a directory set from outside
+    (``JAX_COMPILATION_CACHE_DIR``, which jax reads into its config)
+    is kept, and otherwise the fixed ``<repo>/.jax_cache`` is used —
+    never a temp name, a pid or the time."""
+    from jaxstream.utils.jax_compat import (default_compile_cache_dir,
+                                            place_compile_cache)
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert default_compile_cache_dir() == os.path.join(repo, ".jax_cache")
+    want = str(tmp_path / "outside") if outside else None
+    jax.config.update("jax_compilation_cache_dir", want)
+    got = place_compile_cache()
+    assert got == (want or default_compile_cache_dir())
+    assert jax.config.jax_compilation_cache_dir == got
+    # Placing twice is a no-op, and enablement is never touched.
+    assert place_compile_cache() == got
+    assert jax.config.jax_enable_compilation_cache is False

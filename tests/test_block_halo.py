@@ -85,7 +85,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jaxstream.parallel.halo import make_halo_exchanger
 from jaxstream.parallel.shard_halo import make_block_halo_program
-from jaxstream.utils.jax_compat import shard_map
+from jax import shard_map
 
 n, halo, s = 8, 2, 2
 n_loc = n // s

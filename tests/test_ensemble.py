@@ -155,7 +155,7 @@ def test_batched_face_exchange_bitwise_vs_loop():
     from jaxstream.parallel.shard_cov import (
         CovShardProgram, make_cov_shard_exchange,
         make_cov_shard_exchange_batched)
-    from jaxstream.utils.jax_compat import shard_map
+    from jax import shard_map
 
     grid = build_grid(8, halo=2, radius=EARTH_RADIUS, dtype=jnp.float32)
     setup = setup_sharding({"parallelization": {
@@ -207,7 +207,7 @@ def test_tt_ensemble_exchange_bitwise_vs_loop():
                                     make_tt_strip_exchange, panel_mesh,
                                     shard_factored_state)
     from jaxstream.tt.sphere import factor_panels
-    from jaxstream.utils.jax_compat import shard_map
+    from jax import shard_map
 
     rng = np.random.default_rng(3)
     n, rank, B = 16, 5, 3

@@ -205,7 +205,7 @@ def test_tt_batched_exchange_matches_per_field():
                                     make_tt_strip_exchange_many,
                                     panel_mesh, shard_factored_state)
     from jaxstream.tt.sphere import factor_panels
-    from jaxstream.utils.jax_compat import shard_map
+    from jax import shard_map
 
     rng = np.random.default_rng(11)
     n, rank = 16, 6

@@ -24,6 +24,9 @@ import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+#: A synthetic BENCH_r01..r05 trajectory (same record shape as a bench
+#: envelope; made-up numbers, no chip produced them).
+HISTORY = os.path.join(REPO, "tests", "fixtures", "bench_history")
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "scripts"))
 
@@ -321,13 +324,13 @@ def test_gateway_stats_expose_bucket_costs():
 
 
 # ------------------------------------------------------------- ledger
-def test_ledger_parses_and_passes_real_history():
-    pts = obs_perf.load_bench_history(REPO)
-    assert len(pts) >= 5
+def test_ledger_parses_and_passes_recorded_history():
+    pts = obs_perf.load_bench_history(HISTORY)
+    assert len(pts) == 5
     by_label = {p["label"]: p for p in pts}
     assert by_label["BENCH_r01"]["hardware_class"] == "accelerator"
     assert by_label["BENCH_r05"]["reported_only"] is False
-    assert by_label["BENCH_r05"]["sections"]["headline"] == 3.0019
+    assert by_label["BENCH_r05"]["sections"]["headline"] == 1.25
     assert ("variant:mixed16_carry"
             in by_label["BENCH_r05"]["sections"])
     res = obs_perf.check_trajectory(pts)
@@ -384,12 +387,20 @@ def test_ledger_fixture_fails_loudly_everywhere(tmp_path, capsys):
 
 
 def test_ledger_cli_renders_and_checks_repo_history(capsys):
+    import glob
+
     import perf_ledger
 
-    assert perf_ledger.main([]) == 0
+    paths = sorted(glob.glob(os.path.join(HISTORY, "BENCH_r*.json")))
+    assert perf_ledger.main(paths) == 0
     out = capsys.readouterr().out
     assert "BENCH_r01" in out and "BENCH_r05" in out
     assert "enforced" in out
+    assert perf_ledger.main(["check"] + paths + ["--json"]) == 0
+    res = json.loads(capsys.readouterr().out.strip())
+    assert res["ok"] is True
+    # The repo root's own records (BENCH_r06, a CPU smoke) render and
+    # check clean too.
     assert perf_ledger.main(["check", "--json"]) == 0
     res = json.loads(capsys.readouterr().out.strip())
     assert res["ok"] is True
@@ -455,9 +466,22 @@ def test_roofline_one_definition():
     implementation (obs.perf.roofline_json)."""
     import bench
 
-    ours = obs_perf.roofline_json(1000.0, 96, carry_bytes=2)
-    theirs = bench._roofline_json(1000.0, 96, carry_bytes=2)
+    v5e = "TPU v5 lite"
+    ours = obs_perf.roofline_json(1000.0, 96, carry_bytes=2,
+                                  device_kind=v5e)
+    theirs = bench._roofline_json(1000.0, 96, carry_bytes=2,
+                                  device_kind=v5e)
     assert ours == theirs
-    bf = obs_perf.roofline_json(1000.0, 96, precision="bf16")
+    bf = obs_perf.roofline_json(1000.0, 96, precision="bf16",
+                                device_kind=v5e)
     assert 0.0 < bf["bf16_flop_fraction"] < 1.0
     assert bf["pct_of_mixed_roof"] > 0
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """The peaks come from the device that ran: a CPU run gets no
+    roofline share, and an unknown accelerator is an error, never v5e."""
+    assert obs_perf.roofline_json(1000.0, 96) is None  # this CPU host
+    assert obs_perf.roofline_json(1000.0, 96, device_kind="cpu") is None
+    with pytest.raises(ValueError, match="no roofline peaks"):
+        obs_perf.roofline_json(1000.0, 96, device_kind="TPU v9 mega")

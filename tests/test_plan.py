@@ -484,7 +484,7 @@ def test_serve_plans_resolve():
     assert p.serving and p.placement == "off"
     assert p.key() == "serve_single+classic"
     # Grouped + pallas resolves to the fused member-fold bucket —
-    # mirroring EnsembleServer._impls_for, so scripts/plan.py explain
+    # mirroring EnsembleServer._impl_for, so scripts/plan.py explain
     # --serve names the plan the deployment's telemetry will log.
     pf = plan_for({"model": dict(_COV, backend="pallas_interpret"),
                    "serve": {"group_by_orography": True}},

@@ -11,9 +11,9 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from jaxstream.utils.jax_compat import shard_map
 from jaxstream.config import EARTH_GRAVITY, EARTH_OMEGA, EARTH_RADIUS
 from jaxstream.geometry.cubed_sphere import build_grid
 from jaxstream.models.shallow_water import ShallowWater

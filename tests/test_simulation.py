@@ -265,3 +265,60 @@ def test_simulation_uses_fused_stepper_for_pallas_swe():
     b = np.asarray(sim.state["h"], dtype=np.float64)
     scale = np.max(np.abs(a))
     np.testing.assert_allclose(b, a, atol=2e-4 * scale)
+
+
+# ------------------------------------------------------- no hidden fallback
+def _pallas_cfg(members=1):
+    return {
+        "grid": {"n": 8, "halo": 2, "dtype": "float32"},
+        "model": {"name": "shallow_water_cov", "initial_condition": "tc5",
+                  "backend": "pallas"},
+        "time": {"dt": 600.0, "nsteps": 2},
+        "ensemble": {"members": members},
+        "parallelization": {"num_devices": 1},
+    }
+
+
+@pytest.mark.parametrize("members", [1, 2])
+def test_pallas_fused_build_failure_raises(monkeypatch, members):
+    """A run that asked for backend: pallas never drops silently to the
+    ~2x slower classic tier: a fused-stepper build failure raises."""
+    from jaxstream.models.shallow_water_cov import CovariantShallowWater
+
+    def refuse(self, *a, **kw):
+        raise RuntimeError("mosaic refused the stage kernel")
+
+    monkeypatch.setattr(CovariantShallowWater, "make_fused_step", refuse)
+    with pytest.raises(RuntimeError, match="mosaic refused"):
+        Simulation(_pallas_cfg(members))
+
+
+def test_fused_first_call_failure_surfaces(monkeypatch):
+    """make_fused_step only builds; Mosaic compiles at the first call.
+    A failure there propagates out of run() — nothing swallows it."""
+    from jaxstream.models.shallow_water_cov import CovariantShallowWater
+
+    def lazy_refusal(self, dt, **kw):
+        def step(y, t):
+            raise RuntimeError("Mosaic failed to compile the kernel")
+        return step
+
+    monkeypatch.setattr(CovariantShallowWater, "make_fused_step",
+                        lazy_refusal)
+    sim = Simulation(_pallas_cfg())
+    assert sim._fused_step is not None
+    assert sim.proof.plan_key.split("+")[0] == "fused"
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        sim.run(2)
+
+
+def test_available_devices_has_no_fallback():
+    """'tpu' means jax.devices('tpu'): a CPU-only host raises instead
+    of landing on another backend; unknown kinds are refused."""
+    from jaxstream.parallel.mesh import available_devices
+
+    assert available_devices("cpu")[0].platform == "cpu"
+    with pytest.raises(RuntimeError, match="no tpu devices"):
+        available_devices("tpu")
+    with pytest.raises(ValueError, match="unknown device_type"):
+        available_devices("npu")

@@ -1,8 +1,7 @@
 """Warm pools & the compile tax (jaxstream.serve.warmpool, round 21).
 
 Acceptance criteria, all tier-1 (check_tiers rule 15 keeps this module
-fast and in-process — the rung probe is driven through the pool's
-injectable ``probe=`` fake, never a real child process):
+fast and in-process):
 
   * cache-key invalidation: a rules-version bump, a different plan
     key, a different deployment digest, or a different toolchain
@@ -14,9 +13,8 @@ injectable ``probe=`` fake, never a real child process):
   * a restarted server loads its warm pool: the second server performs
     ZERO XLA compiles and its first-segment results are BYTE-equal to
     the cold server's;
-  * the probe verdict is cached in-process and on disk (one probe per
-    pool directory), and a failed verdict degrades the compile_cache
-    rung with a typed ``fallback`` record;
+  * the compile_cache rung engages where configured and never moves a
+    cache that ``JAX_COMPILATION_CACHE_DIR`` placed;
   * resize() and the speculative compiler REFUSE a scale-up whose
     stamped advisory ``headroom_frac`` breaches
     ``serve.min_headroom_frac`` (typed ``headroom`` record); the
@@ -42,7 +40,7 @@ from jaxstream.serve.warmpool import (WarmPool, deployment_digest,
 
 N, DT = 8, 600.0
 
-_ENV = {"jax": "0.4.37", "jaxlib": "0.4.36", "backend": "cpu",
+_ENV = {"jax": "0.9.0", "jaxlib": "0.9.0", "backend": "cpu",
         "device_count": 1}
 
 
@@ -77,8 +75,8 @@ def test_entry_key_invalidation():
         dict(base, rules_version=3),        # rule-table bump voids all
         dict(base, deploy_digest="e" * 16),
         dict(base, fn="extract"),
-        dict(base, environment=dict(_ENV, jax="0.4.38")),
-        dict(base, environment=dict(_ENV, jaxlib="0.4.37")),
+        dict(base, environment=dict(_ENV, jax="0.9.1")),
+        dict(base, environment=dict(_ENV, jaxlib="0.9.1")),
         dict(base, environment=dict(_ENV, backend="tpu")),
         dict(base, environment=dict(_ENV, device_count=8)),
     ]
@@ -103,9 +101,6 @@ def test_deployment_digest_moves_with_physics(tmp_path):
 def _pool(tmp_path, **kw):
     recs = []
     kw.setdefault("sink_write", recs.append)
-    kw.setdefault("environment", dict(_ENV))
-    kw.setdefault("probe", lambda rung, scratch: {
-        "rung": rung, "ok": False, "detail": "fake probe"})
     return WarmPool(str(tmp_path / "pool"), **kw), recs
 
 
@@ -182,32 +177,42 @@ def test_torn_entry_detected_deleted_recompiled(tmp_path):
     assert pool.stats["corrupt"] == 2
 
 
-def test_probe_verdict_cached_and_gates_cache_rung(tmp_path):
-    """The injected probe runs ONCE per pool directory: the verdict is
-    cached in-process and on disk (a second pool on the same directory
-    never re-probes), and a failed verdict keeps the compile_cache
-    rung OFF with a typed fallback record."""
-    calls = []
+@pytest.fixture
+def restore_cache_config():
+    """Put jax's compile-cache settings back after a test moves them."""
+    from jax.experimental.compilation_cache import compilation_cache
 
-    def fake_probe(rung, scratch):
-        calls.append(rung)
-        return {"rung": rung, "ok": False, "detail": "fake segfault"}
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in saved.items():
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
 
-    pool, recs = _pool(tmp_path, probe=fake_probe,
-                       compile_cache=str(tmp_path / "cc"))
-    v1 = pool.rung_verdict("compile_cache")
-    v2 = pool.rung_verdict("compile_cache")
-    assert calls == ["compile_cache"] and v1 == v2
-    assert not pool.enable_compile_cache()
-    assert any(r["event"] == "fallback"
-               and r["rung"] == "compile_cache" for r in recs)
-    # A sibling pool on the same directory reads the disk verdict.
-    calls2 = []
-    pool2, recs2 = _pool(tmp_path, probe=lambda r, s: calls2.append(r))
-    assert pool2.rung_verdict("compile_cache")["ok"] is False
-    assert calls2 == []
-    assert any(r["event"] == "probe" and r.get("cached")
-               for r in recs2)
+
+@pytest.mark.parametrize("outside", [False, True])
+def test_compile_cache_rung_keeps_outside_dir(tmp_path, monkeypatch,
+                                              restore_cache_config,
+                                              outside):
+    """``serve.compile_cache`` engages the rung directly, and a
+    ``JAX_COMPILATION_CACHE_DIR`` set from outside keeps the cache
+    where it is — the rung never moves it."""
+    outside_dir = str(tmp_path / "outside")
+    if outside:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside_dir)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    pool, recs = _pool(tmp_path, compile_cache=str(tmp_path / "cc"))
+    assert pool.enable_compile_cache() and pool.enable_compile_cache()
+    want = outside_dir if outside else str(tmp_path / "cc")
+    assert jax.config.jax_compilation_cache_dir == want
+    assert pool.summary()["compile_cache"] == want
+    assert os.path.isdir(want)
+    # A pool with no compile_cache leaves the rung off.
+    bare, _ = _pool(tmp_path / "bare")
+    assert not bare.enable_compile_cache()
 
 
 # ------------------------------------------------- server warm restart
