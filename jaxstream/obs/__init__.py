@@ -1,15 +1,19 @@
-"""Run telemetry: on-device metric streams, health guards, structured sinks.
+"""Run telemetry: metric streams, health guards, sinks, spans, forensics.
 
-The in-loop observability subsystem (SURVEY.md §5 "Metrics" made live):
+The observability subsystem:
 :mod:`.metrics` computes the Williamson invariant ladder *inside* the
 jitted segment loop and accumulates it into a small device buffer
 fetched once per segment; :mod:`.monitor` watches the fetched stream
 for NaN/Inf blowups and CFL breaches with a configurable policy;
 :mod:`.sink` writes the run manifest and per-segment records as JSONL
-for ``scripts/telemetry_report.py``.  Wired through
-``Simulation`` by the ``observability:`` config block (off by default —
-enabling it must not perturb the state carry, asserted bitwise in
-tests/test_obs.py).
+for ``scripts/telemetry_report.py`` (wired through ``Simulation`` by
+the ``observability:`` block, off by default — enabling it must not
+perturb the state carry, asserted bitwise in tests/test_obs.py).
+:mod:`.trace` holds the program's host spans (on the profiler's
+timeline and in the always-on ``SPANS`` table) and the per-request span
+trees; :mod:`.flight` the always-on event ring and crash bundles;
+:mod:`.registry` the ``/v1/metrics`` counters; :mod:`.perf` the cost
+stamps and the device-memory watch.
 """
 
 from .flight import (RECORDER, BundleWriter, FlightRecorder,

@@ -38,28 +38,44 @@ root interval; they are why the span-completeness check carries an
 epsilon (:data:`EPSILON_ABS_S` + :data:`EPSILON_FRAC`) instead of
 demanding exact equality.
 
-The span *names* double as :func:`jaxstream.utils.jax_compat.
-named_scope` annotations on the compiled serving segment, so an XLA
-profiler capture (``POST /v1/profile``) shows the same region names the
-sink spans carry.
+A request's sink spans are written only with ``serve.trace`` on, and
+never reach the profiler.  ``jax_compat.named_scope`` names *device*
+ops only (the compiled ops of a scope carry its name).  Host phases
+reach the profiler through :class:`span`, the program's one host-span
+primitive: each span opens a ``jax.profiler.TraceAnnotation`` — so a
+capture (the benchmark's, or ``POST /v1/profile``) shows the host phase
+on the clock of the device ops — and adds its duration to the
+process-wide :data:`SPANS` table, which is always on.  The forecast
+loop (``sim.*``) and the serving loop (``serve.*``) open spans at each
+layer boundary; the request marks reuse the spans' stamps, so there is
+one set of timestamps per boundary.
 
-Stdlib only — no jax, no numpy — so the reassembly helpers stay cheap
-to unit-test and easy to mirror in the stdlib-only ``scripts/`` tools
-(which cannot import this package: ``jaxstream/__init__`` pulls jax).
+The reassembly helpers need nothing but the standard library; the
+stdlib-only ``scripts/`` tools mirror them (they cannot import this
+package: ``jaxstream/__init__`` pulls jax).
 """
 
 from __future__ import annotations
 
 import hashlib
 import json
+import statistics
+import threading
 import time
+from collections import deque
 from typing import Dict, Iterable, List, Optional, Tuple
+
+from ..utils import jax_compat
 
 __all__ = [
     "EPSILON_ABS_S", "EPSILON_FRAC", "PHASE_OF", "SPAN_TIMING_KEYS",
     "ROOT", "GATEWAY_INGRESS", "QUEUE_WAIT", "PACK", "SEGMENT",
     "HOST_WAIT", "BOUNDARY", "FINALIZE_WAIT", "RESULT_FETCH",
     "WRITER_FLUSH", "GATEWAY_EGRESS",
+    "SIM_RUN", "SIM_DISPATCH", "SIM_SYNC", "SIM_SEGMENT", "SIM_BOUNDARY",
+    "SIM_IO", "SIM_COMPILES", "DISPATCH", "IC_BUILD", "FINALIZE",
+    "REFILL", "OBSERVE", "RESERVOIR", "SpanTable", "SPANS", "span",
+    "record_span",
     "RequestTrace", "trace_id_for", "span_id_for", "root_span_id",
     "terminal_span", "spans_by_request", "span_tree", "leaf_sum_s",
     "tree_complete", "span_coverage", "masked_spans",
@@ -109,6 +125,154 @@ PHASE_OF: Dict[str, str] = {
 #: Span-record fields carrying wall-clock time — masked for the
 #: byte-determinism comparison of two runs of the same trace.
 SPAN_TIMING_KEYS = ("start_s", "duration_s")
+
+# --------------------------------------------------------- program spans
+# Forecast loop (``Simulation``).  A segment is its dispatch plus the
+# host's wait for it; the boundary is the host time between one
+# segment's sync and the next dispatch on the same Simulation, across
+# ``run()`` calls.
+SIM_RUN = "sim.run"                   # one whole run() call
+SIM_DISPATCH = "sim.dispatch"         # the segment function call
+SIM_SYNC = "sim.sync"                 # the host's wait for the segment
+SIM_SEGMENT = "sim.segment"           # dispatch start -> sync end
+SIM_BOUNDARY = "sim.boundary"         # sync end -> next dispatch start
+SIM_IO = "sim.io"                     # history / checkpoint work
+SIM_COMPILES = "sim.compiles"         # counter: dispatches that compiled
+# Serving loop (``EnsembleServer._run_batch``); PACK, HOST_WAIT,
+# BOUNDARY, RESULT_FETCH and WRITER_FLUSH above name the same phases.
+# BOUNDARY runs from the health fetch's end to the next dispatch and
+# holds FINALIZE, REFILL and OBSERVE.
+DISPATCH = "serve.dispatch"           # the masked segment's dispatch
+IC_BUILD = "serve.ic_build"           # one request's initial state
+FINALIZE = "serve.finalize"           # extract + writer submit
+REFILL = "serve.refill"               # late IC builds + inject
+OBSERVE = "serve.observe"             # stats, registry, sink, tick, bundle
+
+#: Recent durations the span table keeps per name (for medians).
+RESERVOIR = 4096
+
+
+class _SpanStat:
+    __slots__ = ("count", "total_s", "max_s", "recent")
+
+    def __init__(self, reservoir: int):
+        self.count = 0
+        self.total_s = 0.0
+        self.max_s = 0.0
+        self.recent: deque = deque(maxlen=reservoir)
+
+
+class SpanTable:
+    """Per span name: count, total and longest duration, and the most
+    recent ``(duration, attrs)`` pairs (at most ``reservoir``), so
+    medians and per-attribute splits can be read; plus named counters.
+
+    Written from any thread (the serving loop, the result writer) under
+    one lock; :meth:`summary` returns plain dicts.
+    """
+
+    def __init__(self, reservoir: int = RESERVOIR):
+        self.reservoir = int(reservoir)
+        self._lock = threading.Lock()
+        self._spans: Dict[str, _SpanStat] = {}
+        self._counters: Dict[str, int] = {}
+
+    def add(self, name: str, seconds: float,
+            attrs: Optional[dict] = None) -> None:
+        with self._lock:
+            st = self._spans.get(name)
+            if st is None:
+                st = self._spans[name] = _SpanStat(self.reservoir)
+            st.count += 1
+            st.total_s += seconds
+            if seconds > st.max_s:
+                st.max_s = seconds
+            st.recent.append((seconds, attrs or None))
+
+    def count(self, name: str, n: int = 1) -> None:
+        with self._lock:
+            self._counters[name] = self._counters.get(name, 0) + n
+
+    def clear(self) -> None:
+        """Drop every span and counter (tests; a benchmark run reads
+        the table of its own process)."""
+        with self._lock:
+            self._spans.clear()
+            self._counters.clear()
+
+    def summary(self) -> dict:
+        """``{"spans": {name: {"count", "total_s", "max_s", "median_s",
+        "recent_s", "recent_attrs"}}, "counters": {name: n}}``; the
+        median is over the recent durations."""
+        with self._lock:
+            spans = {name: (st.count, st.total_s, st.max_s,
+                            list(st.recent))
+                     for name, st in self._spans.items()}
+            counters = dict(self._counters)
+        out = {}
+        for name, (n, total, longest, recent) in spans.items():
+            durs = [d for d, _ in recent]
+            out[name] = {"count": n, "total_s": total, "max_s": longest,
+                         "median_s": statistics.median(durs),
+                         "recent_s": durs,
+                         "recent_attrs": [a for _, a in recent]}
+        return {"spans": out, "counters": counters}
+
+
+#: The process-wide table every span adds to.  Always on.
+SPANS = SpanTable()
+
+# Bound once: a span's own cost is on every boundary of both loops.
+_recording = jax_compat.profiler_recording
+_clock = time.perf_counter
+
+
+class span:
+    """``with span(name, **attrs) as sp:`` — one program span.
+
+    On entry it opens a profiler annotation (when a session records)
+    and stamps ``sp.t0``, unless the caller passes a stamp it already
+    took as ``t0``; on exit it stamps ``sp.t1``, sets ``sp.seconds``
+    and adds the duration with ``attrs`` to :data:`SPANS`.  A caller
+    that learns inside the span that it must stay out of the
+    statistics (a segment that compiled) sets ``sp.keep = False``.
+    """
+
+    __slots__ = ("name", "attrs", "t0", "t1", "seconds", "keep", "_ann")
+
+    def __init__(self, name: str, t0: Optional[float] = None, **attrs):
+        self.name = name
+        self.attrs = attrs
+        self.t0 = t0
+        self.t1 = self.seconds = None
+        self.keep = True
+
+    def __enter__(self) -> "span":
+        self._ann = ann = (
+            jax_compat.trace_annotation(self.name, **self.attrs)
+            if _recording() else None)
+        if ann is not None:
+            ann.__enter__()
+        if self.t0 is None:
+            self.t0 = _clock()
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        t1 = self.t1 = _clock()
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
+        seconds = self.seconds = t1 - self.t0
+        if self.keep:
+            SPANS.add(self.name, seconds, self.attrs)
+        return False
+
+
+def record_span(name: str, t0: float, t1: float, **attrs) -> float:
+    """A span from two stamps the caller already took: its duration
+    goes to :data:`SPANS` (the profiler shows the interval as the gap
+    between the spans around it).  Returns the duration."""
+    SPANS.add(name, t1 - t0, attrs)
+    return t1 - t0
 
 
 def trace_id_for(request_id: str) -> str:

@@ -1500,15 +1500,16 @@ class EnsembleServer:
         plan_key = bk.proof.plan_key if bk.proof is not None else None
         self.stats["batches"] += 1
 
-        if self._trace_on:
-            # queue.wait ends (and serve.pack opens) for the whole
-            # initial batch at one stamp — the IC builds + the single
-            # stack below are the batch's shared packing work.
-            t_pack = time.perf_counter()
-            for r in batch:
-                self._mark(r.id, obs_trace.PACK, t_pack)
-        trees = [self._member_tree(r) for r in batch]
-        carry = bk.stack(trees + [trees[0]] * (B - len(batch)))
+        with obs_trace.span(obs_trace.PACK, bucket=B) as sp:
+            if self._trace_on:
+                # queue.wait ends (and serve.pack opens) for the whole
+                # initial batch at one stamp — the IC builds + the
+                # single stack below are the batch's shared packing
+                # work.
+                for r in batch:
+                    self._mark(r.id, obs_trace.PACK, sp.t0)
+            trees = [self._ic_build(r, "pack") for r in batch]
+            carry = bk.stack(trees + [trees[0]] * (B - len(batch)))
         slots: List[Optional[_Slot]] = (
             [_Slot(r) for r in batch] + [None] * (B - len(batch)))
         rem = np.zeros(B, np.int64)
@@ -1526,17 +1527,18 @@ class EnsembleServer:
         allow_refill = True
 
         while any(sl is not None for sl in slots):
-            w0 = time.perf_counter()
-            active_mask = [sl is not None for sl in slots]
-            active_before = sum(active_mask)
-            resident = [(i, sl.req.id) for i, sl in enumerate(slots)
-                        if sl is not None]
-            # The black box's in-flight view: updated BEFORE the
-            # segment dispatches, so the crash bundle committed at
-            # this boundary names exactly the members a kill during
-            # the segment would strand.
-            self._resident = [rid for _, rid in resident]
-            carry, _, nf = bk.seg(carry, bk.put_rem(rem))
+            with obs_trace.span(obs_trace.DISPATCH, bucket=B) as sp:
+                active_mask = [sl is not None for sl in slots]
+                active_before = sum(active_mask)
+                resident = [(i, sl.req.id) for i, sl in enumerate(slots)
+                            if sl is not None]
+                # The black box's in-flight view: updated BEFORE the
+                # segment dispatches, so the crash bundle committed at
+                # this boundary names exactly the members a kill during
+                # the segment would strand.
+                self._resident = [rid for _, rid in resident]
+                carry, _, nf = bk.seg(carry, bk.put_rem(rem))
+            w0 = sp.t0
             # The health stream rides a HostFetch: its d2h copy chases
             # the segment's compute while the host does the boundary
             # work that does NOT depend on it — completion is pure
@@ -1552,221 +1554,236 @@ class EnsembleServer:
                 r = self._pop(group)
                 if r is None:
                     break
-                if self._trace_on:
-                    self._mark(r.id, obs_trace.PACK)
-                prepped.append((r, self._member_tree(r)))
-            hw0 = time.perf_counter()
-            nf_host = np.asarray(nf_fetch.resolve(),
-                                 np.float64).reshape(-1)
-            hw1 = time.perf_counter()
+                prepped.append((r, self._ic_build(r, "prefetch")))
+            with obs_trace.span(obs_trace.HOST_WAIT) as sp:
+                nf_host = np.asarray(nf_fetch.resolve(),
+                                     np.float64).reshape(-1)
+            hw0, hw1 = sp.t0, sp.t1
             host_wait = hw1 - hw0
             wall = hw1 - w0
-            steps_by_slot = rem - new_rem
-            member_steps = int(np.sum(steps_by_slot))
-            rem = new_rem
-            for i, sl in enumerate(slots):
-                if sl is not None:
-                    sl.done = sl.req.nsteps - int(rem[i])
-            if self._trace_on:
-                # Three leaves per resident request per segment, at the
-                # SHARED boundary stamps (w0/hw0/hw1): device compute,
-                # health-stream host wait, then boundary work (evict/
-                # extract/refill) which the next segment mark — or the
-                # finalize mark — closes.  Segment leaves carry the
-                # operator attribution: bucket, plan key, chip, steps.
-                for i, rid in resident:
-                    self._mark(rid, obs_trace.SEGMENT, w0, bucket=B,
-                               plan=plan_key,
-                               chip=(chips[i] if chips is not None
-                                     else 0),
-                               steps=int(steps_by_slot[i]))
-                    self._mark(rid, obs_trace.HOST_WAIT, hw0)
-                    self._mark(rid, obs_trace.BOUNDARY, hw1)
-            # Per-segment progress stream (round 14, the gateway's
-            # hook): one event per slot active during this segment,
-            # emitted BEFORE any finalization from this boundary is
-            # queued — no wall-clock fields, so the stream is
-            # deterministic for a given packing.
-            if self.on_segment is not None:
-                progress = [
-                    {"id": sl.req.id, "steps_done": sl.done,
-                     "nsteps": sl.req.nsteps, "t": sl.done * dt,
-                     "bucket": B, "done": bool(rem[i] == 0)}
-                    for i, sl in enumerate(slots) if sl is not None]
-                try:
-                    self.on_segment(progress)
-                except Exception as e:   # a subscriber bug must not
-                    log.warning(         # kill the batch
-                        "serve: on_segment hook failed (%s: %s)",
-                        type(e).__name__, e)
-            # Testing hook: host-side injection into the health STREAM
-            # (never the state), mirroring observability.fault_step.
-            fi = s.fault_member
-            if (fi >= 0 and cfg.observability.fault_step >= 0
-                    and not self._fault_fired and fi < B
-                    and slots[fi] is not None
-                    and slots[fi].done >= cfg.observability.fault_step):
-                nf_host[fi] = max(nf_host[fi], 1.0)
-                self._fault_fired = True
-            completed = evicted = 0
-            if self.monitor is not None:
-                counts = np.where(
-                    [sl is not None for sl in slots], nf_host, 0.0)
-                steps = [sl.done if sl is not None else 0 for sl in slots]
-                ts = [d * dt for d in steps]
-                # 'halt' policy raises here (HealthError) — the writer
-                # flush in serve()'s finally still lands prior
-                # results, and the speculatively popped refill
-                # requests go back to the queue head (they were
-                # admitted; a guard trip must not lose them).
-                try:
-                    events = self.monitor.check_members(
-                        steps, ts, counts, chips=chips)
-                except BaseException:
-                    if prepped:
-                        self.queue.requeue(r for r, _ in prepped)
-                    raise
-                for ev in events:
-                    i = ev["member"]
-                    self._finish(slots[i], "evicted", None, ev)
-                    rem[i] = 0
-                    slots[i] = None
-                    evicted += 1
-                    if self._sink is not None:
-                        # The event is already a schema-valid 'guard'
-                        # record; under placement it names the chip.
-                        self._sink_write(ev)
-                if events:
-                    self.metrics.counter_inc(
-                        "jaxstream_guard_events_total", len(events))
-            for i, sl in enumerate(slots):
-                if sl is not None and rem[i] == 0:
-                    fetch = HostFetch(bk.extract(carry, jnp.int32(i)))
-                    self._finish(sl, "ok", fetch)
-                    slots[i] = None
-                    completed += 1
-            refilled = 0
-            if allow_refill:
-                for i in range(B):
-                    if slots[i] is not None:
-                        continue
-                    if prepped:
-                        r, tree = prepped.pop(0)
-                    else:
-                        r = self._pop(group)
-                        if r is None:
-                            break
-                        if self._trace_on:
-                            self._mark(r.id, obs_trace.PACK)
-                        tree = self._member_tree(r)
-                    carry = bk.inject(carry, jnp.int32(i),
-                                      bk.put_member(tree))
-                    rem[i] = r.nsteps
-                    slots[i] = _Slot(r)
-                    refilled += 1
-            # Prepped requests can never be left over: free slots >=
-            # predicted completions (eviction only adds frees) and the
-            # refill loop scans every slot, consuming prepped first.
-            # A popped request silently dropped would be a lost-
-            # traffic bug, so the invariant fails loudly.
-            assert not prepped, (
-                "serve refill invariant broken: speculatively popped "
-                f"requests left unslotted: {[r.id for r, _ in prepped]}")
-            st = self.stats
-            st["segments"] += 1
-            st["last_occupancy"] = active_before / B
-            st["refills"] += refilled
-            st["member_steps"] += member_steps
-            st["occupancy_sum"] += active_before / B
-            st["utilization_sum"] += member_steps / (B * seg)
-            st["completed"] += completed
-            st["evicted"] += evicted
-            st["host_wait_s"] += host_wait
-            m = self.metrics
-            m.counter_inc("jaxstream_segments_total")
-            if member_steps:
-                m.counter_inc("jaxstream_member_steps_total",
-                              member_steps)
-            if completed:
-                m.counter_inc("jaxstream_requests_completed_total",
-                              completed, status="ok")
-            if evicted:
-                m.counter_inc("jaxstream_requests_completed_total",
-                              evicted, status="evicted")
-            m.gauge_set("jaxstream_queue_depth", len(self.queue))
-            m.gauge_set("jaxstream_occupancy", active_before / B)
-            m.observe("jaxstream_segment_wall_seconds", wall,
-                      buckets=WALL_BUCKETS_S)
-            m.observe("jaxstream_host_wait_seconds", host_wait,
-                      buckets=HOST_WAIT_BUCKETS_S)
-            for j in range(m_shards):
-                occ_j = (sum(active_mask[j * per_shard:
-                                         (j + 1) * per_shard])
-                         / per_shard)
-                util_j = (float(np.sum(
-                    steps_by_slot[j * per_shard:(j + 1) * per_shard]))
-                    / (per_shard * seg))
-                m.gauge_set("jaxstream_chip_occupancy", occ_j,
-                            chip=str(j))
-                m.gauge_set("jaxstream_chip_utilization", util_j,
-                            chip=str(j))
-            if self._sink is not None:
-                rec = {
-                    "kind": "serve", "bucket": B, "group": group,
-                    "plan": (bk.proof.plan_key
-                             if bk.proof is not None else None),
-                    "proof_verdict": (bk.proof.verdict
-                                      if bk.proof is not None
-                                      else None),
-                    "occupancy": round(active_before / B, 4),
-                    "utilization": round(member_steps / (B * seg), 4),
-                    "queue_depth": len(self.queue),
-                    "wall_s": round(wall, 6),
-                    "host_wait_s": round(host_wait, 6),
-                    "completed": completed, "evicted": evicted,
-                    "refilled": refilled, "member_steps": member_steps,
-                }
+            # The boundary: from the health fetch's end to this
+            # iteration's end, where the next dispatch follows.
+            with obs_trace.span(obs_trace.BOUNDARY, t0=hw1, bucket=B):
+                steps_by_slot = rem - new_rem
+                member_steps = int(np.sum(steps_by_slot))
+                rem = new_rem
+                for i, sl in enumerate(slots):
+                    if sl is not None:
+                        sl.done = sl.req.nsteps - int(rem[i])
                 if self._trace_on:
-                    # Which requests this segment advanced (slot
-                    # order) — the dashboard's live in-flight view.
-                    rec["trace_ids"] = [
-                        obs_trace.trace_id_for(rid)
-                        for _, rid in resident]
-                if plan.sharded:
-                    rec["placement"] = plan.mode
-                    rec["devices"] = plan.num_devices
-                    rec["chip_occupancy"] = [
-                        round(sum(active_mask[j * per_shard:
-                                              (j + 1) * per_shard])
-                              / per_shard, 4)
-                        for j in range(m_shards)]
-                    rec["chip_utilization"] = [
-                        round(float(np.sum(
-                            steps_by_slot[j * per_shard:
-                                          (j + 1) * per_shard]))
-                            / (per_shard * seg), 4)
-                        for j in range(m_shards)]
-                self._sink_write(rec)
-            flight.record("serve.boundary", bucket=B,
-                          active=active_before, completed=completed,
-                          evicted=evicted, refilled=refilled,
-                          queue_depth=len(self.queue))
-            # Autoscale hook, once per segment boundary — queue depth
-            # and last_occupancy are fresh here.  A resize ends this
-            # batch's refill (see cap0 note above).
-            self._tick(tick)
-            # Post-boundary resident set (completions/evictions above
-            # freed slots; refill re-occupied some) before the live
-            # bundle re-commit — throttled, so a fast segment cadence
-            # costs at most ~4 commits/second.
-            self._resident = [sl.req.id for sl in slots
-                              if sl is not None]
-            self.flight_commit()
-            if allow_refill and self._active_max != cap0:
-                allow_refill = False
-                log.info("serve: active cap resized %d -> %d mid-"
-                         "batch; batch (B=%d) winds down without "
-                         "refilling", cap0, self._active_max, B)
+                    # Three leaves per resident request per segment, at the
+                    # SHARED boundary stamps (w0/hw0/hw1): device compute,
+                    # health-stream host wait, then boundary work (evict/
+                    # extract/refill) which the next segment mark — or the
+                    # finalize mark — closes.  Segment leaves carry the
+                    # operator attribution: bucket, plan key, chip, steps.
+                    for i, rid in resident:
+                        self._mark(rid, obs_trace.SEGMENT, w0, bucket=B,
+                                   plan=plan_key,
+                                   chip=(chips[i] if chips is not None
+                                         else 0),
+                                   steps=int(steps_by_slot[i]))
+                        self._mark(rid, obs_trace.HOST_WAIT, hw0)
+                        self._mark(rid, obs_trace.BOUNDARY, hw1)
+                # Per-segment progress stream (round 14, the gateway's
+                # hook): one event per slot active during this segment,
+                # emitted BEFORE any finalization from this boundary is
+                # queued — no wall-clock fields, so the stream is
+                # deterministic for a given packing.
+                if self.on_segment is not None:
+                    progress = [
+                        {"id": sl.req.id, "steps_done": sl.done,
+                         "nsteps": sl.req.nsteps, "t": sl.done * dt,
+                         "bucket": B, "done": bool(rem[i] == 0)}
+                        for i, sl in enumerate(slots) if sl is not None]
+                    try:
+                        self.on_segment(progress)
+                    except Exception as e:   # a subscriber bug must not
+                        log.warning(         # kill the batch
+                            "serve: on_segment hook failed (%s: %s)",
+                            type(e).__name__, e)
+                # Testing hook: host-side injection into the health STREAM
+                # (never the state), mirroring observability.fault_step.
+                fi = s.fault_member
+                if (fi >= 0 and cfg.observability.fault_step >= 0
+                        and not self._fault_fired and fi < B
+                        and slots[fi] is not None
+                        and slots[fi].done >= cfg.observability.fault_step):
+                    nf_host[fi] = max(nf_host[fi], 1.0)
+                    self._fault_fired = True
+                completed = evicted = 0
+                if self.monitor is not None:
+                    counts = np.where(
+                        [sl is not None for sl in slots], nf_host, 0.0)
+                    steps = [sl.done if sl is not None else 0 for sl in slots]
+                    ts = [d * dt for d in steps]
+                    # 'halt' policy raises here (HealthError) — the writer
+                    # flush in serve()'s finally still lands prior
+                    # results, and the speculatively popped refill
+                    # requests go back to the queue head (they were
+                    # admitted; a guard trip must not lose them).
+                    try:
+                        events = self.monitor.check_members(
+                            steps, ts, counts, chips=chips)
+                    except BaseException:
+                        if prepped:
+                            self.queue.requeue(r for r, _ in prepped)
+                        raise
+                    for ev in events:
+                        i = ev["member"]
+                        self._finish(slots[i], "evicted", None, ev)
+                        rem[i] = 0
+                        slots[i] = None
+                        evicted += 1
+                        if self._sink is not None:
+                            # The event is already a schema-valid 'guard'
+                            # record; under placement it names the chip.
+                            self._sink_write(ev)
+                    if events:
+                        self.metrics.counter_inc(
+                            "jaxstream_guard_events_total", len(events))
+                with obs_trace.span(obs_trace.FINALIZE):
+                    for i, sl in enumerate(slots):
+                        if sl is not None and rem[i] == 0:
+                            fetch = HostFetch(bk.extract(carry, jnp.int32(i)))
+                            self._finish(sl, "ok", fetch)
+                            slots[i] = None
+                            completed += 1
+                with obs_trace.span(obs_trace.REFILL):
+                    refilled = 0
+                    if allow_refill:
+                        for i in range(B):
+                            if slots[i] is not None:
+                                continue
+                            if prepped:
+                                r, tree = prepped.pop(0)
+                            else:
+                                r = self._pop(group)
+                                if r is None:
+                                    break
+                                tree = self._ic_build(r, "late")
+                            carry = bk.inject(carry, jnp.int32(i),
+                                              bk.put_member(tree))
+                            rem[i] = r.nsteps
+                            slots[i] = _Slot(r)
+                            refilled += 1
+                # Prepped requests can never be left over: free slots >=
+                # predicted completions (eviction only adds frees) and the
+                # refill loop scans every slot, consuming prepped first.
+                # A popped request silently dropped would be a lost-
+                # traffic bug, so the invariant fails loudly.
+                assert not prepped, (
+                    "serve refill invariant broken: speculatively popped "
+                    f"requests left unslotted: {[r.id for r, _ in prepped]}")
+                with obs_trace.span(obs_trace.OBSERVE):
+                    st = self.stats
+                    st["segments"] += 1
+                    st["last_occupancy"] = active_before / B
+                    st["refills"] += refilled
+                    st["member_steps"] += member_steps
+                    st["occupancy_sum"] += active_before / B
+                    st["utilization_sum"] += member_steps / (B * seg)
+                    st["completed"] += completed
+                    st["evicted"] += evicted
+                    st["host_wait_s"] += host_wait
+                    m = self.metrics
+                    m.counter_inc("jaxstream_segments_total")
+                    if member_steps:
+                        m.counter_inc("jaxstream_member_steps_total",
+                                      member_steps)
+                    if completed:
+                        m.counter_inc("jaxstream_requests_completed_total",
+                                      completed, status="ok")
+                    if evicted:
+                        m.counter_inc("jaxstream_requests_completed_total",
+                                      evicted, status="evicted")
+                    m.gauge_set("jaxstream_queue_depth", len(self.queue))
+                    m.gauge_set("jaxstream_occupancy", active_before / B)
+                    m.observe("jaxstream_segment_wall_seconds", wall,
+                              buckets=WALL_BUCKETS_S)
+                    m.observe("jaxstream_host_wait_seconds", host_wait,
+                              buckets=HOST_WAIT_BUCKETS_S)
+                    for j in range(m_shards):
+                        occ_j = (sum(active_mask[j * per_shard:
+                                                 (j + 1) * per_shard])
+                                 / per_shard)
+                        util_j = (float(np.sum(
+                            steps_by_slot[j * per_shard:(j + 1) * per_shard]))
+                            / (per_shard * seg))
+                        m.gauge_set("jaxstream_chip_occupancy", occ_j,
+                                    chip=str(j))
+                        m.gauge_set("jaxstream_chip_utilization", util_j,
+                                    chip=str(j))
+                    if self._sink is not None:
+                        rec = {
+                            "kind": "serve", "bucket": B, "group": group,
+                            "plan": (bk.proof.plan_key
+                                     if bk.proof is not None else None),
+                            "proof_verdict": (bk.proof.verdict
+                                              if bk.proof is not None
+                                              else None),
+                            "occupancy": round(active_before / B, 4),
+                            "utilization": round(member_steps / (B * seg), 4),
+                            "queue_depth": len(self.queue),
+                            "wall_s": round(wall, 6),
+                            "host_wait_s": round(host_wait, 6),
+                            "completed": completed, "evicted": evicted,
+                            "refilled": refilled, "member_steps": member_steps,
+                        }
+                        if self._trace_on:
+                            # Which requests this segment advanced (slot
+                            # order) — the dashboard's live in-flight view.
+                            rec["trace_ids"] = [
+                                obs_trace.trace_id_for(rid)
+                                for _, rid in resident]
+                        if plan.sharded:
+                            rec["placement"] = plan.mode
+                            rec["devices"] = plan.num_devices
+                            rec["chip_occupancy"] = [
+                                round(sum(active_mask[j * per_shard:
+                                                      (j + 1) * per_shard])
+                                      / per_shard, 4)
+                                for j in range(m_shards)]
+                            rec["chip_utilization"] = [
+                                round(float(np.sum(
+                                    steps_by_slot[j * per_shard:
+                                                  (j + 1) * per_shard]))
+                                    / (per_shard * seg), 4)
+                                for j in range(m_shards)]
+                        self._sink_write(rec)
+                    flight.record("serve.boundary", bucket=B,
+                                  active=active_before, completed=completed,
+                                  evicted=evicted, refilled=refilled,
+                                  queue_depth=len(self.queue))
+                    # Autoscale hook, once per segment boundary — queue depth
+                    # and last_occupancy are fresh here.  A resize ends this
+                    # batch's refill (see cap0 note above).
+                    self._tick(tick)
+                    # Post-boundary resident set (completions/evictions above
+                    # freed slots; refill re-occupied some) before the live
+                    # bundle re-commit — throttled, so a fast segment cadence
+                    # costs at most ~4 commits/second.
+                    self._resident = [sl.req.id for sl in slots
+                                      if sl is not None]
+                    self.flight_commit()
+                if allow_refill and self._active_max != cap0:
+                    allow_refill = False
+                    log.info("serve: active cap resized %d -> %d mid-"
+                             "batch; batch (B=%d) winds down without "
+                             "refilling", cap0, self._active_max, B)
+
+    def _ic_build(self, req: ScenarioRequest, where: str):
+        """One request's member tree as a ``serve.ic_build`` span;
+        ``where`` is ``pack`` (the batch's initial pack), ``prefetch``
+        (built while the segment runs) or ``late`` (inside the
+        boundary).  A refill's build opens its ``serve.pack`` mark; the
+        initial pack marks the whole batch at the pack span's start."""
+        with obs_trace.span(obs_trace.IC_BUILD, where=where) as sp:
+            if where != "pack" and self._trace_on:
+                self._mark(req.id, obs_trace.PACK, sp.t0)
+            tree = self._member_tree(req)
+        obs_trace.SPANS.count(f"{obs_trace.IC_BUILD}.{where}")
+        return tree
 
     def _mark(self, rid: str, name: str, t: Optional[float] = None,
               **attrs) -> None:
@@ -1793,28 +1810,30 @@ class EnsembleServer:
     def _finalize(self, req: ScenarioRequest, status: str, done: int,
                   fetch: Optional[HostFetch], event: Optional[dict]):
         tr = self._traces.pop(req.id, None) if self._trace_on else None
-        if tr is not None:
-            tr.mark(obs_trace.RESULT_FETCH)
-        fields = {}
-        if fetch is not None:
-            host = fetch.resolve()
-            fields = {k: host[k] for k in req.outputs if k in host}
-        if tr is not None:
-            tr.mark(obs_trace.WRITER_FLUSH)
+        with obs_trace.span(obs_trace.RESULT_FETCH) as sp:
+            if tr is not None:
+                tr.mark(obs_trace.RESULT_FETCH, sp.t0)
+            fields = {}
+            if fetch is not None:
+                host = fetch.resolve()
+                fields = {k: host[k] for k in req.outputs if k in host}
         t_final = done * self.config.time.dt
-        out_dir = self.config.serve.output_dir
-        if out_dir and fields:
-            from ..io.history import HistoryWriter
+        with obs_trace.span(obs_trace.WRITER_FLUSH) as sp:
+            if tr is not None:
+                tr.mark(obs_trace.WRITER_FLUSH, sp.t0)
+            out_dir = self.config.serve.output_dir
+            if out_dir and fields:
+                from ..io.history import HistoryWriter
 
-            hw = HistoryWriter(
-                os.path.join(out_dir, req.id),
-                attrs={"request": req.id, "ic": req.ic,
-                       "nsteps": req.nsteps, "status": status})
-            hw.append(fields, t_final)
+                hw = HistoryWriter(
+                    os.path.join(out_dir, req.id),
+                    attrs={"request": req.id, "ic": req.ic,
+                           "nsteps": req.nsteps, "status": status})
+                hw.append(fields, t_final)
         # The result-ready instant: latency_s and the trace root close
         # on the SAME stamp, so the span tree's leaf sum telescopes to
         # the reported latency exactly (obs.trace module docstring).
-        t_end = time.perf_counter()
+        t_end = sp.t1
         latency = (t_end - req.submitted_wall
                    if req.submitted_wall is not None else 0.0)
         res = RequestResult(

@@ -44,6 +44,7 @@ from .models.diffusion import ThermalDiffusion
 from .models.shallow_water import ShallowWater
 from .obs import flight
 from .obs import metrics as obs_metrics
+from .obs import trace as obs_trace
 from .obs.monitor import HealthMonitor
 from .obs.sink import TelemetrySink, run_manifest
 from .parallel.mesh import (setup_ensemble_sharding, setup_sharding,
@@ -55,6 +56,7 @@ from .physics import initial_conditions as ics
 from .stepping import (integrate, integrate_with_metrics, jit_integrate,
                        time_carry)
 from .utils import diagnostics as diag
+from .utils import jax_compat
 from .utils.logging import get_logger
 
 __all__ = ["Simulation", "run_from_config"]
@@ -304,6 +306,9 @@ class Simulation:
         self._writer: Optional[BackgroundWriter] = None
         self._host_wait = 0.0
         self._t_carry = None
+        # End stamp of the last segment's sim.sync: the next dispatch
+        # closes the sim.boundary it opens, across run() calls.
+        self._sync_end: Optional[float] = None
 
         io = cfg.io
         self.history: Optional[HistoryWriter] = None
@@ -1076,7 +1081,9 @@ class Simulation:
         :func:`jaxstream.stepping.integrate_with_metrics` instead —
         same state ops in the same order — with signature
         ``fn(y, t, step0) -> (y, t, buf)`` and an ``obs_samples``
-        attribute carrying the buffer's column count.
+        attribute carrying the buffer's column count.  A callable that
+        wraps its jit carries it as ``jitted``, whose compile count
+        :meth:`_dispatch` reads.
 
         Known trade: the metric buffer's ``(k_metrics, samples)`` shape
         is static, so instrumented segments compile once per DISTINCT
@@ -1141,6 +1148,7 @@ class Simulation:
                 return _f(y, t, step0)
 
             call.obs_samples = samples
+            call.jitted = jfn
             return call
         if self._fused_step is not None:
             m, fused = self.model, self._fused_step
@@ -1168,6 +1176,7 @@ class Simulation:
         def fn(y, t, _k=k // spc):
             return run(y, t, _k)
 
+        fn.jitted = run
         return fn
 
     def _segment_fn(self, k: int) -> Callable:
@@ -1177,27 +1186,82 @@ class Simulation:
             self._segment_cache[k] = fn
         return fn
 
+    def _dispatch(self, fn: Callable, k: int, *args):
+        """One segment dispatch as a ``sim.dispatch`` span; returns
+        ``(outputs, phases)``.
+
+        A dispatch that grew the segment jit's compile count compiled:
+        it counts in ``sim.compiles``, leaves a flight ``compile`` event
+        naming the step, and stays out of the span statistics, as does
+        its segment.  ``phases`` carries the dispatch's start stamp and
+        seconds, the ``sim.boundary`` before it (None on a
+        Simulation's first dispatch) and the compile flag."""
+        jitted = getattr(fn, "jitted", fn)
+        n0 = jax_compat.compile_count(jitted)
+        with obs_trace.span(obs_trace.SIM_DISPATCH, steps=k) as sp:
+            out = fn(*args)
+            n1 = jax_compat.compile_count(jitted)
+            compiled = n0 is not None and n1 is not None and n1 > n0
+            sp.keep = not compiled
+        boundary = None
+        if self._sync_end is not None:
+            boundary = sp.t0 - self._sync_end
+            if not compiled:
+                obs_trace.record_span(obs_trace.SIM_BOUNDARY,
+                                      self._sync_end, sp.t0)
+        if compiled:
+            obs_trace.SPANS.count(obs_trace.SIM_COMPILES, n1 - n0)
+            flight.record("compile", step=self.step_count, k=k,
+                          delta=n1 - n0, seconds=round(sp.seconds, 6))
+        return out, {"t0": sp.t0, "dispatch_s": sp.seconds,
+                     "boundary_s": boundary, "compiled": compiled}
+
+    def _close_segment(self, ph: dict, sync, k: int, step_end: int,
+                       **fields) -> float:
+        """Close one segment at its ``sim.sync`` span: the
+        ``sim.segment`` statistics (unless it compiled), the next
+        boundary's start stamp and the flight ``segment`` event with
+        its phase split.  Returns the segment's seconds."""
+        seconds = sync.t1 - ph["t0"]
+        if not ph["compiled"]:
+            obs_trace.record_span(obs_trace.SIM_SEGMENT, ph["t0"],
+                                  sync.t1, steps=k)
+        self._sync_end = sync.t1
+        b = ph["boundary_s"]
+        flight.record("segment", step=step_end, k=k, **fields,
+                      dispatch_s=round(ph["dispatch_s"], 6),
+                      sync_s=round(sync.seconds, 6),
+                      boundary_s=None if b is None else round(b, 6),
+                      compiled=ph["compiled"])
+        return seconds
+
     def _run_segment(self, k: int):
         fn = self._segment_fn(k)
-        if getattr(fn, "obs_samples", 0) > 0:
-            # Instrumented segment: the metric buffer rides the compiled
-            # loop and is fetched with ONE device->host transfer here —
-            # which also blocks on the segment, so `wall` is the true
-            # segment wall time.
-            step0, t0 = self.step_count, self.t
-            wall0 = time.perf_counter()
-            self.state, t, buf = fn(self.state, self.t,
-                                    jnp.asarray(step0))
-            host = obs_metrics.fetch_buffer(buf)
-            wall = time.perf_counter() - wall0
-            self.t = float(t)
-            self.step_count += k
+        step0, t0 = self.step_count, self.t
+        instrumented = getattr(fn, "obs_samples", 0) > 0
+        with obs_trace.span(obs_trace.SIM_SEGMENT, steps=k) as seg:
+            # The profiler's sim.segment; its statistics come from the
+            # dispatch and sync stamps (_close_segment).
+            seg.keep = False
+            if instrumented:
+                # Instrumented segment: the metric buffer rides the
+                # compiled loop and is fetched with ONE device->host
+                # transfer, which also waits for the segment.
+                (self.state, t, buf), ph = self._dispatch(
+                    fn, k, self.state, self.t, jnp.asarray(step0))
+            else:
+                (self.state, t), ph = self._dispatch(
+                    fn, k, self.state, self.t)
+            with obs_trace.span(obs_trace.SIM_SYNC) as sync:
+                sync.keep = not ph["compiled"]
+                host = (obs_metrics.fetch_buffer(buf) if instrumented
+                        else None)
+                self.t = float(t)
+        self.step_count += k
+        wall = self._close_segment(ph, sync, k, self.step_count)
+        if instrumented:
             self._ingest_telemetry(host, step0, t0, k, wall,
                                    self.step_count, self.t)
-            return
-        self.state, t = fn(self.state, self.t)
-        self.t = float(t)
-        self.step_count += k
 
     def _ingest_telemetry(self, host, step0: int, t0: float, k: int,
                           wall: float, step_end: int, t_end: float,
@@ -1371,8 +1435,26 @@ class Simulation:
         accelerator: copy it (``np.asarray``) before continuing the
         simulation if you need to keep it.
         """
-        total = self.total_steps() if nsteps is None else nsteps
-        start = self.step_count
+        with obs_trace.span(obs_trace.SIM_RUN) as sp:
+            start = self.step_count
+            self._run(self.total_steps() if nsteps is None else nsteps)
+        ran = self.step_count - start
+        days = ran * self.config.time.dt / 86400.0
+        log.info(
+            "ran %d steps (%.2f sim-days) in %.2fs wall -> %.2f sim-days/sec",
+            ran, days, sp.seconds,
+            days / sp.seconds if sp.seconds > 0 else float("inf"),
+        )
+        return self.state
+
+    def _io(self, fn: Callable, *args) -> None:
+        """History or checkpoint work as a ``sim.io`` span, counted in
+        the host wait the next telemetry record reports."""
+        with obs_trace.span(obs_trace.SIM_IO) as sp:
+            fn(*args)
+        self._host_wait += sp.seconds
+
+    def _run(self, total: int):
         io = self.config.io
         strides = [s for s in (io.history_stride, io.checkpoint_stride) if s > 0]
         seg = math.gcd(*strides) if strides else 0
@@ -1393,7 +1475,6 @@ class Simulation:
                           if n in obs_metrics.CONSERVED},
             })
             obs.wrote_initial = True
-        wall0 = time.perf_counter()
         try:
             if io.async_pipeline.enabled:
                 self._run_loop_async(total, seg, io)
@@ -1402,22 +1483,17 @@ class Simulation:
                     k = (min(seg, total - self.step_count) if seg
                          else total - self.step_count)
                     self._run_segment(k)
-                    flight.record("segment", step=self.step_count, k=k)
                     if (io.history_stride
                             and self.step_count % io.history_stride == 0):
-                        w0 = time.perf_counter()
-                        self._emit()
-                        self._host_wait += time.perf_counter() - w0
+                        self._io(self._emit)
                     if (
                         self.checkpoints is not None
                         and self.step_count % io.checkpoint_stride == 0
                     ):
-                        w0 = time.perf_counter()
-                        self.checkpoints.save(self.step_count, self.state,
-                                              self.t)
+                        self._io(self.checkpoints.save, self.step_count,
+                                 self.state, self.t)
                         flight.record("checkpoint",
                                       step=self.step_count)
-                        self._host_wait += time.perf_counter() - w0
         except BaseException as e:
             # HealthError / unhandled exception: flush the black box
             # BEFORE unwinding (the sink records ride the same open
@@ -1425,14 +1501,6 @@ class Simulation:
             self._flight_dump(type(e).__name__)
             raise
         jax.block_until_ready(self.state)
-        wall = time.perf_counter() - wall0
-        ran = self.step_count - start
-        days = ran * self.config.time.dt / 86400.0
-        log.info(
-            "ran %d steps (%.2f sim-days) in %.2fs wall -> %.2f sim-days/sec",
-            ran, days, wall, days / wall if wall > 0 else float("inf"),
-        )
-        return self.state
 
     # ------------------------------------------------------- async pipeline
     def _run_loop_async(self, total: int, seg: int, io):
@@ -1478,11 +1546,12 @@ class Simulation:
                 step0 = self.step_count
                 buf = None
                 if samples > 0:
-                    self.state, self._t_carry, buf = fn(
-                        self.state, self._t_carry, jnp.asarray(step0))
+                    (self.state, self._t_carry, buf), ph = self._dispatch(
+                        fn, k, self.state, self._t_carry,
+                        jnp.asarray(step0))
                 else:
-                    self.state, self._t_carry = fn(self.state,
-                                                   self._t_carry)
+                    (self.state, self._t_carry), ph = self._dispatch(
+                        fn, k, self.state, self._t_carry)
                 self.step_count += k
                 want_hist = bool(
                     io.history_stride
@@ -1508,7 +1577,7 @@ class Simulation:
                     "t": HostFetch(self._t_carry),
                     "buf": HostFetch(buf) if samples > 0 else None,
                     "state": HostFetch(snap) if snap is not None else None,
-                    "hist": want_hist, "ckpt": want_ckpt,
+                    "hist": want_hist, "ckpt": want_ckpt, "phases": ph,
                 }
                 # The double buffer: only now — with this segment's
                 # dispatch in flight — resolve the previous boundary.
@@ -1564,13 +1633,15 @@ class Simulation:
         (segment record + guard events land on disk) but skips the
         history/checkpoint writes, exactly like the synchronous loop,
         which raises before reaching them."""
-        t_host = float(np.asarray(b["t"].resolve()))
+        ph = b["phases"]
+        with obs_trace.span(obs_trace.SIM_SYNC) as sync:
+            sync.keep = not ph["compiled"]
+            t_host = float(np.asarray(b["t"].resolve()))
         self.t = t_host
-        now = time.perf_counter()
-        wall = now - self._seg_anchor
-        self._seg_anchor = now
-        flight.record("segment", step=b["step_end"], k=b["k"],
-                      wall_s=round(wall, 6))
+        wall = sync.t1 - self._seg_anchor
+        self._seg_anchor = sync.t1
+        self._close_segment(ph, sync, b["k"], b["step_end"],
+                            wall_s=round(wall, 6))
         host_state = (b["state"].resolve() if b["state"] is not None
                       else None)
         tasks = []
@@ -1593,9 +1664,7 @@ class Simulation:
                               (b["step_end"], host_state, t_host)))
         finally:
             if tasks:
-                w0 = time.perf_counter()
-                writer.submit(_run_tasks, tasks)
-                self._host_wait += time.perf_counter() - w0
+                self._io(writer.submit, _run_tasks, tasks)
         return t_host
 
 

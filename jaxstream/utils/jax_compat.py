@@ -19,9 +19,11 @@ __all__ = ["compile_count", "copy_to_host_async",
            "device_memory_stats", "enable_compile_cache",
            "executable_serialization_available",
            "memory_analysis", "named_scope", "place_compile_cache",
-           "profiler_available", "serialize_executable",
+           "profiler_available", "profiler_recording",
+           "serialize_executable",
            "serialize_stablehlo", "stablehlo_serialization_available",
-           "start_profiler_trace", "stop_profiler_trace"]
+           "start_profiler_trace", "stop_profiler_trace",
+           "trace_annotation"]
 
 
 def named_scope(name: str):
@@ -34,6 +36,20 @@ def named_scope(name: str):
     name lives in HLO metadata only.
     """
     return jax.named_scope(name)
+
+
+#: ``profiler_recording()`` is True while a profiler session records.
+#: The bare ``TraceAnnotation.is_enabled`` (no Python frame): a span
+#: tests it before building an annotation, so with no session it costs
+#: this one check.
+profiler_recording = jax.profiler.TraceAnnotation.is_enabled
+
+
+def trace_annotation(name: str, **attrs):
+    """A ``jax.profiler.TraceAnnotation`` for one host span: ``name``,
+    with ``attrs`` as event metadata, on the profiler's host timeline,
+    the clock the device ops of the same capture run on."""
+    return jax.profiler.TraceAnnotation(name, **attrs)
 
 
 def profiler_available() -> bool:

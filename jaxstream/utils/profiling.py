@@ -12,18 +12,13 @@ first-class tool:
   * :func:`roofline` turns (flops, bytes, measured seconds) into the
     deck's chart: arithmetic intensity, achieved vs roof throughput,
     and which resource binds.
-  * :class:`StepTimer` measures steady-state step time without compile
-    skew; :func:`trace` wraps ``jax.profiler`` for TensorBoard traces.
 """
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
-import math
-import statistics
 import time
-from typing import Callable, Dict, Optional, Sequence
+from typing import Callable, Dict, Optional
 
 import jax
 
@@ -32,7 +27,7 @@ __all__ = [
     "TPU_V5E_VPU", "TPU_V5E_VPU_BF16", "DEVICE_ROOFS", "device_roofs",
     "mixed_vpu_roof",
     "cost_analysis", "analytic_cov_step_cost", "roofline", "Roofline",
-    "StepTimer", "median_chain_seconds", "steady_state_rate", "trace",
+    "median_chain_seconds", "steady_state_rate",
 ]
 
 
@@ -419,67 +414,3 @@ def steady_state_rate(run, y, k1: int = 3000, k2: int = 15000):
     raise RuntimeError(
         f"steady_state_rate: inconsistent windows (t1={t1:.4f}s for {k1} "
         f"steps, t2={t2:.4f}s for {k2}) after 3 attempts")
-
-
-class StepTimer:
-    """Steady-state step timing: call ``t = timer(step_fn, state)``.
-
-    Blocks on the result each rep, so each sample is one full device
-    round-trip; the first ``discard`` samples (compile + warmup) are
-    dropped from the stats.
-    """
-
-    def __init__(self, discard: int = 1):
-        self.discard = discard
-        self.samples: list = []
-
-    def time(self, fn: Callable, *args, reps: int = 10, **kwargs):
-        out = None
-        for _ in range(self.discard + reps):
-            t0 = time.perf_counter()
-            out = fn(*args, **kwargs)
-            jax.block_until_ready(out)
-            self.samples.append(time.perf_counter() - t0)
-        return out
-
-    @property
-    def kept(self) -> Sequence[float]:
-        return self.samples[self.discard:]
-
-    @staticmethod
-    def _percentile(sorted_samples: Sequence[float], q: float) -> float:
-        """Nearest-rank (ceil) percentile: the smallest sample >= the
-        q-quantile.  The previous p90 used ``int(n * 0.9) - 1``, which
-        under-indexes for small n (n=2 returned the MINIMUM as p90;
-        n=10 was only right by accident of truncation) — the ceil
-        convention is exact for all n >= 1."""
-        return sorted_samples[max(0, math.ceil(q * len(sorted_samples)) - 1)]
-
-    def stats(self) -> Dict[str, float]:
-        k = sorted(self.kept)
-        if not k:
-            return {}
-        return {
-            "n": len(k),
-            "mean_s": statistics.fmean(k),
-            "min_s": k[0],
-            "p50_s": self._percentile(k, 0.50),
-            "p90_s": self._percentile(k, 0.90),
-            "p99_s": self._percentile(k, 0.99),
-        }
-
-    def sim_days_per_sec(self, dt: float, steps_per_call: int = 1) -> float:
-        s = self.stats()
-        if not s:
-            return 0.0
-        return steps_per_call * dt / 86400.0 / s["p50_s"]
-
-
-@contextlib.contextmanager
-def trace(logdir: str):
-    """``with trace('/tmp/tb'):`` — jax.profiler trace for TensorBoard/xprof."""
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

@@ -1,14 +1,11 @@
 """Profiling/roofline subsystem tests (deck p.19 analysis frame as code)."""
 
-import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from jaxstream.utils.profiling import (
     TPU_V4_CLASS,
     Roofline,
-    StepTimer,
     cost_analysis,
     roofline,
 )
@@ -77,55 +74,3 @@ def test_analytic_cov_step_cost_matches_design_bisection():
     assert r.binding == "compute"
     assert 1.0 < r.achieved_tflops < 3.5
     assert "compute-bound" in r.report()
-
-
-def test_step_timer_discards_compile():
-    timer = StepTimer(discard=1)
-
-    @jax.jit
-    def step(x):
-        return x * 1.0001
-
-    x = jnp.ones((32, 32))
-    out = timer.time(step, x, reps=5)
-    assert np.all(np.isfinite(np.asarray(out)))
-    s = timer.stats()
-    assert s["n"] == 5
-    assert s["min_s"] <= s["p50_s"] <= s["p90_s"] <= s["p99_s"]
-    assert timer.sim_days_per_sec(dt=86400.0) > 0  # 1 sim-day/step
-
-
-def _timer_with(samples):
-    t = StepTimer(discard=0)
-    t.samples = list(samples)
-    return t
-
-
-def test_step_timer_percentiles_nearest_rank():
-    """Ceil-convention nearest-rank percentiles (round-8 satellite):
-    the old p90 under-indexed for small n — ``int(n*0.9) - 1`` returned
-    the MINIMUM of a 2-sample set."""
-    # n=2: p90 must be the larger sample (the old code returned k[0]).
-    s = _timer_with([2.0, 1.0]).stats()
-    assert s["p90_s"] == 2.0
-    assert s["p99_s"] == 2.0
-
-    # n=10 with distinct values 1..10: ceil(0.9*10)-1 = idx 8 -> 9.0,
-    # p99 -> the max, and the median follows the SAME convention
-    # (ceil(0.5*10)-1 = idx 4 -> 5.0; one percentile rule, not two).
-    s = _timer_with(range(1, 11)).stats()
-    assert s["p50_s"] == 5.0
-    assert s["p90_s"] == 9.0
-    assert s["p99_s"] == 10.0
-
-    # n=1: every percentile is the single sample.
-    s = _timer_with([3.5]).stats()
-    assert s["p50_s"] == s["p90_s"] == s["p99_s"] == 3.5
-
-    # n=100: p90 is the 90th smallest, p99 the 99th.
-    s = _timer_with(range(100)).stats()
-    assert s["p90_s"] == 89
-    assert s["p99_s"] == 98
-
-    # Empty timer still returns {} (no crash on the discard-only case).
-    assert StepTimer(discard=0).stats() == {}
