@@ -73,9 +73,9 @@ __all__ = [
     "HOST_WAIT", "BOUNDARY", "FINALIZE_WAIT", "RESULT_FETCH",
     "WRITER_FLUSH", "GATEWAY_EGRESS",
     "SIM_RUN", "SIM_DISPATCH", "SIM_SYNC", "SIM_SEGMENT", "SIM_BOUNDARY",
-    "SIM_IO", "SIM_COMPILES", "DISPATCH", "IC_BUILD", "FINALIZE",
-    "REFILL", "OBSERVE", "RESERVOIR", "SpanTable", "SPANS", "span",
-    "record_span",
+    "SIM_IO", "SIM_COMPILES", "DISPATCH", "IC_BUILD", "IC_BASE",
+    "FINALIZE", "REFILL", "OBSERVE", "RESERVOIR", "SpanTable", "SPANS",
+    "span", "record_span",
     "RequestTrace", "trace_id_for", "span_id_for", "root_span_id",
     "terminal_span", "spans_by_request", "span_tree", "leaf_sum_s",
     "tree_complete", "span_coverage", "masked_spans",
@@ -144,6 +144,7 @@ SIM_COMPILES = "sim.compiles"         # counter: dispatches that compiled
 # holds FINALIZE, REFILL and OBSERVE.
 DISPATCH = "serve.dispatch"           # the masked segment's dispatch
 IC_BUILD = "serve.ic_build"           # one request's initial state
+IC_BASE = "serve.ic_base"             # a family's seed-free IC inputs
 FINALIZE = "serve.finalize"           # extract + writer submit
 REFILL = "serve.refill"               # late IC builds + inject
 OBSERVE = "serve.observe"             # stats, registry, sink, tick, bundle

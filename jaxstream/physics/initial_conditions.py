@@ -30,6 +30,8 @@ __all__ = [
     "williamson_tc5",
     "williamson_tc6",
     "galewsky",
+    "perturbation_base",
+    "perturbed_member",
     "perturbed_ensemble",
 ]
 
@@ -203,6 +205,35 @@ def williamson_tc6(
     return h, vec
 
 
+def perturbation_base(grid: CubedSphereGrid, h_ext):
+    """The float64 inputs of :func:`perturbed_member` that do not depend
+    on the seed: ``(h64, rhat64, href)`` — the base height and the unit
+    position vectors ``xyz / radius`` at extended cell centers, both
+    upcast from what the grid and ``h_ext`` hold (the grid dtype: f32 on
+    the chip), and the perturbation scale ``mean|h|``."""
+    h64 = _np(h_ext)
+    rhat64 = _np(grid.xyz) / grid.radius             # (3, 6, M, M)
+    return h64, rhat64, float(np.mean(np.abs(h64)))
+
+
+def perturbed_member(h64, rhat64, href: float, rng, amplitude: float):
+    """One perturbed height member ``(6, M, M)`` in float64: the shared
+    recipe of :func:`perturbed_ensemble` and the serving tier.
+
+    Draws a random unit-normalized combination of three ``l = 1``
+    spherical modes ``ghat_j . rhat`` from ``rng`` (nine then three
+    standard normals), max-normalizes it over the extended grid, ghosts
+    included, and returns ``h64 + amplitude * href * mode``.  The
+    inputs are :func:`perturbation_base`'s.
+    """
+    g = rng.standard_normal((3, 3))
+    g /= np.linalg.norm(g, axis=1, keepdims=True)
+    w = rng.standard_normal(3)
+    mode = np.einsum("jk,k...->...", g * w[:, None], rhat64)
+    mode /= max(float(np.abs(mode).max()), 1e-300)
+    return h64 + amplitude * href * mode
+
+
 def perturbed_ensemble(
     grid: CubedSphereGrid,
     h_ext,
@@ -217,28 +248,24 @@ def perturbed_ensemble(
     ``mode`` a random unit-normalized combination of three ``l = 1``
     spherical modes ``ghat_j . rhat`` (the gentlest fields that still
     decorrelate trajectories — the standard perturbed-IC recipe for TC5
-    / Galewsky spread studies).  Everything is evaluated analytically at
-    extended cell centers in float64 (ghosts exact, like every IC in
-    this module) with a deterministic ``numpy`` generator, so a given
-    ``(seed, members)`` pair reproduces bit-identical ICs across runs
-    and processes.  The wind is left unperturbed — height-only
-    perturbations keep members balanced to the same order as the base
-    state, so no member needs its own spin-up.
+    / Galewsky spread studies).  The grid's ``xyz`` and ``h_ext`` are
+    read in the grid dtype (f32 on the chip) and upcast to float64
+    (:func:`perturbation_base`); each member is then
+    :func:`perturbed_member` in float64 at extended cell centers, ghosts
+    included, drawing from one deterministic ``numpy`` generator in
+    member order, so a given ``(seed, members)`` pair reproduces
+    bit-identical ICs across runs and processes; member 1 of ``seed`` is
+    the height the serving tier builds for a request of that seed.
+    The wind is left unperturbed — height-only perturbations keep
+    members balanced to the same order as the base state, so no member
+    needs its own spin-up.
     """
     if members < 1:
         raise ValueError(f"members must be >= 1, got {members}")
-    h = _np(h_ext)
-    rhat = _np(grid.xyz) / grid.radius               # (3, 6, M, M)
+    h64, rhat64, href = perturbation_base(grid, h_ext)
     rng = np.random.default_rng(seed)
-    href = float(np.mean(np.abs(h)))
-    out = [h]
-    for _ in range(members - 1):
-        g = rng.standard_normal((3, 3))
-        g /= np.linalg.norm(g, axis=1, keepdims=True)
-        w = rng.standard_normal(3)
-        mode = np.einsum("jk,k...->...", g * w[:, None], rhat)
-        mode /= max(float(np.abs(mode).max()), 1e-300)
-        out.append(h + amplitude * href * mode)
+    out = [h64] + [perturbed_member(h64, rhat64, href, rng, amplitude)
+                   for _ in range(members - 1)]
     return jnp.asarray(np.stack(out), dtype=grid.sqrtg.dtype)
 
 

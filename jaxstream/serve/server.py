@@ -96,7 +96,7 @@ from ..plan import rules as _plan_rules
 from ..plan.rules import RULES_VERSION as _PLAN_RULES_VERSION
 from .queue import (AdmissionRefused, QueueFull, RequestQueue,
                     ServerDraining)
-from .request import RequestResult, ScenarioRequest
+from .request import SWE_FAMILIES, RequestResult, ScenarioRequest
 
 __all__ = ["EnsembleServer", "serve_requests"]
 
@@ -318,6 +318,7 @@ class EnsembleServer:
         self._draining = False
         self._models: Dict[str, object] = {}
         self._ics: Dict[str, tuple] = {}
+        self._ic_bases: Dict[str, tuple] = {}
         self._b_zero = None
         self._b_oro = None
         self._buckets: Dict[tuple, _Bucket] = {}
@@ -719,9 +720,30 @@ class EnsembleServer:
                 nu4=p.hyperdiffusion, backend=m.backend)
         return self._models[group]
 
+    def _ic_base(self, family: str):
+        """Cached seed-free request inputs per family, built once as a
+        ``serve.ic_base`` span: the float64 perturbation inputs
+        ``(h64, rhat64, href)`` (:func:`...perturbation_base`) and the
+        unperturbed interior state.  Seeded requests share its ``u``:
+        the recipe perturbs height only, and stack and inject copy
+        their operands."""
+        base = self._ic_bases.get(family)
+        if base is None:
+            with obs_trace.span(obs_trace.IC_BASE, family=family):
+                h, v, _ = self._ic(family)
+                group = SWE_FAMILIES[family] if self._grouping else "any"
+                base = (ics.perturbation_base(self.grid, h),
+                        self._model(group).initial_state(h, v))
+            obs_trace.SPANS.count(obs_trace.IC_BASE)
+            self._ic_bases[family] = base
+        return base
+
     def _request_state(self, req: ScenarioRequest):
         """A request's interior initial state (deterministic in seed).
 
+        A seeded request runs :func:`...perturbed_member` on the
+        family's cached base (:meth:`_ic_base`) in float64, casts and
+        slices the interior on the host and uploads ``h`` once.
         ``ic: 'array'`` requests carry the interior state themselves
         (round 18): the arrays go on device as-is — byte-preserving,
         so a checkpointed member or an EnKF analysis state resubmitted
@@ -729,11 +751,14 @@ class EnsembleServer:
         by :meth:`validate_request`)."""
         if req.ic == "array":
             return {k: jnp.asarray(v) for k, v in req.state.items()}
-        h, v, _ = self._ic(req.ic)
-        if req.seed >= 0 and req.amplitude != 0.0:
-            h = ics.perturbed_ensemble(self.grid, h, 2, seed=req.seed,
-                                       amplitude=req.amplitude)[1]
-        return self._model(self._group(req)).initial_state(h, v)
+        (h64, rhat64, href), state = self._ic_base(req.ic)
+        if req.seed < 0 or req.amplitude == 0.0:
+            return dict(state)
+        h = ics.perturbed_member(h64, rhat64, href,
+                                 np.random.default_rng(req.seed),
+                                 req.amplitude)
+        h = self.grid.interior(h.astype(state["h"].dtype))
+        return {"h": jnp.asarray(h), "u": state["u"]}
 
     def validate_request(self, req: ScenarioRequest) -> None:
         """Admission-time deployment validation (raises ValueError).
@@ -977,9 +1002,8 @@ class EnsembleServer:
 
     def _warm_member_tree(self, group: str):
         family = "tc5" if group == "oro" else "tc2"
-        st = self._model(group).initial_state(*self._ic(family)[:2])
+        st = dict(self._ic_base(family)[1])
         if not self._grouping:
-            st = dict(st)
             st["b"] = self._b_ext(family)
         return st
 

@@ -7,6 +7,9 @@ Acceptance criteria of the serving tier, all tier-1 (check_tiers rule
     (stepping.integrate_masked unit);
   * a single request through the B=1 bucket is BITWISE identical to a
     plain unbatched ``Simulation`` run of the same scenario;
+  * a seeded request's initial state, built from the family's cached
+    base, is BITWISE the float64 perturbation recipe's (a verbatim
+    oracle), as is each ``perturbed_ensemble`` member;
   * packing + boundary refill are deterministic (two identical servers
     produce byte-identical results) and each packed member's trajectory
     is exactly its own solo run;
@@ -214,6 +217,61 @@ def test_serve_sink_records_and_report(served_pair):
 
 
 # ------------------------------------------------- parity & resilience
+def _oracle_ensemble(grid, h_ext, members, seed, amplitude):
+    """The float64 perturbed-height recipe written out whole, apart from
+    the library's split into a seed-free base and one member: the
+    oracle the shared recipe and the server's cached build are held
+    to, bit for bit."""
+    h = np.asarray(h_ext, dtype=np.float64)
+    rhat = np.asarray(grid.xyz, dtype=np.float64) / grid.radius
+    rng = np.random.default_rng(seed)
+    href = float(np.mean(np.abs(h)))
+    out = [h]
+    for _ in range(members - 1):
+        g = rng.standard_normal((3, 3))
+        g /= np.linalg.norm(g, axis=1, keepdims=True)
+        w = rng.standard_normal(3)
+        mode = np.einsum("jk,k...->...", g * w[:, None], rhat)
+        mode /= max(float(np.abs(mode).max()), 1e-300)
+        out.append(h + amplitude * href * mode)
+    return jnp.asarray(np.stack(out), dtype=grid.sqrtg.dtype)
+
+
+@pytest.fixture(scope="module")
+def c16_server():
+    srv = EnsembleServer(_cfg(grid={"n": 16}))
+    yield srv
+    srv.close()
+
+
+@pytest.mark.parametrize("seed", [0, 1, 7, 123])
+@pytest.mark.parametrize("family", ["tc5", "galewsky", "tc2"])
+def test_request_state_bitwise_vs_oracle(c16_server, family, seed):
+    """A seeded request's interior (h, u), built from the family's
+    cached base, is bitwise the oracle's member 1 through
+    ``initial_state``; ``perturbed_ensemble`` gives the oracle's
+    members one by one."""
+    from jaxstream.physics import initial_conditions as ics
+
+    srv, amp = c16_server, 1e-3
+    h, v, _ = srv._ic(family)
+    want = srv._model("any").initial_state(
+        _oracle_ensemble(srv.grid, h, 2, seed, amp)[1], v)
+    got = srv._request_state(ScenarioRequest(
+        id="p", ic=family, nsteps=1, seed=seed, amplitude=amp))
+    for k in ("h", "u"):
+        assert got[k].dtype == want[k].dtype, k
+        np.testing.assert_array_equal(np.asarray(got[k]),
+                                      np.asarray(want[k]), err_msg=k)
+    members = ics.perturbed_ensemble(srv.grid, h, 4, seed=seed,
+                                     amplitude=amp)
+    oracle = _oracle_ensemble(srv.grid, h, 4, seed, amp)
+    assert members.dtype == oracle.dtype
+    for i in range(4):
+        np.testing.assert_array_equal(np.asarray(members[i]),
+                                      np.asarray(oracle[i]), err_msg=i)
+
+
 def test_b1_request_bitwise_vs_plain_simulation(tmp_path):
     """A request served alone through the B=1 bucket is bitwise the
     unbatched Simulation run of the same scenario — the single-request

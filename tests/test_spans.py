@@ -13,6 +13,8 @@ Tier-1 (check_tiers rules 11 and 14: non-slow, in-process):
   * an untraced ``EnsembleServer`` run gives one ``serve.boundary`` per
     segment and one ``serve.ic_build`` per packed request, split by
     where it was built, and its writer thread's spans land in the table;
+  * seeded requests of one family share one ``serve.ic_base`` build of
+    its seed-free inputs, and two seeds give two heights over one wind;
   * the table's reservoir stays bounded, its median is exact, and
     concurrent writers lose no update.
 """
@@ -20,6 +22,7 @@ Tier-1 (check_tiers rules 11 and 14: non-slow, in-process):
 import glob
 import threading
 
+import numpy as np
 import pytest
 
 import jax
@@ -151,6 +154,47 @@ def test_server_spans_untraced():
     # The result writer's spans: one fetch and one flush per request.
     assert spans["result.fetch"]["count"] == len(lengths)
     assert spans["writer.flush"]["count"] == len(lengths)
+
+
+def test_ic_base_built_once_per_family():
+    """Seeded tc5 requests at C16 share one ``serve.ic_base`` build of
+    the family's seed-free inputs; each request still opens its own
+    ``serve.ic_build``, and two seeds give two heights over one wind."""
+    obs_trace.SPANS.clear()
+    srv = EnsembleServer(load_config({
+        "grid": {"n": 16}, "time": {"dt": 600.0},
+        "model": {"name": "shallow_water_cov", "backend": "jnp"},
+        "serve": {"buckets": "2", "segment_steps": 2,
+                  "queue_capacity": 16, "group_by_orography": True},
+    }))
+    built = {}
+    member_tree = srv._member_tree
+
+    def record(req):
+        built[req.id] = tree = member_tree(req)
+        return tree
+
+    srv._member_tree = record
+    lengths = (2, 4, 2, 6, 2, 4)
+    for i, n in enumerate(lengths):
+        srv.submit(ScenarioRequest(id=f"r{i}", ic="tc5", nsteps=n,
+                                   seed=10 + i, amplitude=1e-3))
+    srv.serve()
+    srv.close()
+    assert sorted(r.status for r in srv.results.values()) == (
+        ["ok"] * len(lengths))
+
+    table = obs_trace.SPANS.summary()
+    spans, counters = table["spans"], table["counters"]
+    assert counters[obs_trace.IC_BASE] == 1
+    assert spans[obs_trace.IC_BASE]["recent_attrs"] == [{"family": "tc5"}]
+    assert spans[obs_trace.IC_BUILD]["count"] == len(lengths)
+    where = [a["where"] for a in spans[obs_trace.IC_BUILD]["recent_attrs"]]
+    assert where.count("pack") == 2
+    assert set(where) <= {"pack", "prefetch", "late"}
+    a, b = built["r0"], built["r1"]
+    assert not np.array_equal(np.asarray(a["h"]), np.asarray(b["h"]))
+    np.testing.assert_array_equal(np.asarray(a["u"]), np.asarray(b["u"]))
 
 
 def test_reservoir_bounded_and_median_exact():
