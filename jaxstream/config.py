@@ -250,8 +250,9 @@ class PlacementConfig:
     12) — default off, and when off the server is bit-for-bit the
     single-chip round-11 path.  ``mode: member`` shards the packed
     member axis across a 1-D ``('member',)`` device mesh (a B=16
-    bucket on 8 chips runs 2 members/chip; zero wire traffic; classic
-    jnp RHS only — GSPMD cannot split the fused kernels' member fold);
+    bucket on 8 chips runs 2 members/chip; zero wire traffic): the
+    GSPMD-partitioned classic stepper under ``backend: jnp``, the
+    fused member-fold kernels per chip on a grouped Pallas server);
     ``mode: panel`` spreads each request's 6 faces over the 2-D
     ``('panel', 'member')`` mesh through the batched-exchange ensemble
     stepper (large grids; num_devices must be a multiple of 6;

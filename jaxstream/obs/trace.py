@@ -74,7 +74,8 @@ __all__ = [
     "WRITER_FLUSH", "GATEWAY_EGRESS",
     "SIM_RUN", "SIM_DISPATCH", "SIM_SYNC", "SIM_SEGMENT", "SIM_BOUNDARY",
     "SIM_IO", "SIM_COMPILES", "DISPATCH", "IC_BUILD", "IC_BASE",
-    "FINALIZE", "REFILL", "OBSERVE", "RESERVOIR", "SpanTable", "SPANS",
+    "FINALIZE", "REFILL", "INJECT", "INJECT_BYTES", "OBSERVE",
+    "RESERVOIR", "SpanTable", "SPANS",
     "span", "record_span",
     "RequestTrace", "trace_id_for", "span_id_for", "root_span_id",
     "terminal_span", "spans_by_request", "span_tree", "leaf_sum_s",
@@ -147,6 +148,11 @@ IC_BUILD = "serve.ic_build"           # one request's initial state
 IC_BASE = "serve.ic_base"             # a family's seed-free IC inputs
 FINALIZE = "serve.finalize"           # extract + writer submit
 REFILL = "serve.refill"               # late IC builds + inject
+INJECT = "serve.inject"               # one member into its slot (refill)
+INJECT_BYTES = "serve.inject_bytes"   # counter: host-to-device bytes the
+                                      # injects upload (a member's h under
+                                      # placement; 0 on one chip, where the
+                                      # build uploads it)
 OBSERVE = "serve.observe"             # stats, registry, sink, tick, bundle
 
 #: Recent durations the span table keeps per name (for medians).

@@ -35,7 +35,7 @@ from ..utils.logging import get_logger
 
 __all__ = ["ShardingSetup", "available_devices", "setup_sharding",
            "shard_state", "setup_ensemble_sharding",
-           "shard_ensemble_state"]
+           "shard_ensemble_state", "member_spec", "member_shard_map"]
 
 log = get_logger(__name__)
 
@@ -307,3 +307,22 @@ def shard_ensemble_state(setup: ShardingSetup, state):
     return jax.tree_util.tree_map(
         lambda a: jax.device_put(a, setup.ensemble_sharding_for(a.ndim)),
         state)
+
+
+def member_spec(axis: int) -> P:
+    """PartitionSpec splitting one array's member axis ``axis`` over
+    the 1-D ``('member',)`` mesh; every other axis stays whole."""
+    return P(*((None,) * axis + ("member",)))
+
+
+def member_shard_map(fn, mesh: Mesh, in_axes, out_axes):
+    """``fn`` run per chip on its own members: ``shard_map`` over the
+    ``('member',)`` mesh, each argument and result split along the
+    member axis that ``in_axes`` / ``out_axes`` (pytrees of ints
+    matching them) name.  ``fn`` sees the local batch ``B // chips``
+    and issues no collective, so members never leave their chip."""
+    from jax import shard_map
+
+    specs = lambda axes: jax.tree_util.tree_map(member_spec, axes)
+    return shard_map(fn, mesh=mesh, in_specs=specs(in_axes),
+                     out_specs=specs(out_axes), check_vma=False)

@@ -235,7 +235,8 @@ def _build_serving(plan, ctx: PlanContext) -> BuiltStepper:
     """The serving placements' masked-segment programs, composed the
     way :class:`jaxstream.serve.server.EnsembleServer._build_bucket`
     composes them (panel: shard_map ensemble stepper; member/single:
-    the vmapped classic)."""
+    the vmapped classic, or the grouped fused member fold — per chip
+    under ``shard_map`` when member-placed)."""
     import jax.numpy as jnp
 
     from .. import stepping
@@ -249,9 +250,25 @@ def _build_serving(plan, ctx: PlanContext) -> BuiltStepper:
         # the member axis rides the stage kernels' grid inside the
         # masked segment.
         m = ctx.model("pallas_interpret")
-        pstep = m.make_fused_step(dt, ensemble=B)
         axes = ENSEMBLE_CARRY_AXES
         carry = m.ensemble_compact_state(ctx.batched_state(B))
+        if plan.placement == "member":
+            from ..parallel.mesh import member_shard_map
+
+            mesh = ctx.ensemble_setup(B, "member", plan.num_devices).mesh
+            local = m.make_fused_step(dt, ensemble=B // plan.num_devices)
+
+            def seg_local(y, rem, _s=local, _ax=axes):
+                y, _, rem = stepping.integrate_masked(
+                    _s, y, 0.0, rem, seg, dt, _ax)
+                return y, rem
+
+            seg_fn = attach_proof(
+                member_shard_map(seg_local, mesh, (axes, 0), (axes, 0)),
+                plan)
+            return BuiltStepper(plan, seg_fn, (carry, rem0),
+                                kind="masked")
+        pstep = m.make_fused_step(dt, ensemble=B)
     else:
         m = ctx.model()
         axes = ENSEMBLE_STATE_AXES

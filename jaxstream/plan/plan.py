@@ -27,7 +27,19 @@ from typing import Optional
 from . import rules
 from .rules import PlanError, reject_illegal
 
-__all__ = ["CapabilityPlan", "plan_for", "PlanError"]
+__all__ = ["CapabilityPlan", "plan_for", "PlanError", "serves_fused"]
+
+def serves_fused(cfg) -> bool:
+    """Whether a serving bucket of ``cfg`` (single-chip or member-placed)
+    runs the fused member-fold masked segment: grouped by orography, a
+    Pallas backend, SSPRK3 and no hyperdiffusion.  ``plan_for`` and
+    ``EnsembleServer`` both decide by it; rule ``serve-member-jnp``
+    states the same condition over the plan's axes."""
+    return (bool(cfg.serve.group_by_orography)
+            and cfg.model.backend.startswith("pallas")
+            and cfg.time.scheme == "ssprk3"
+            and cfg.physics.hyperdiffusion == 0.0)
+
 
 def _ic_family():
     from ..simulation import IC_FAMILY
@@ -318,12 +330,7 @@ def plan_for(config, serving: bool = False) -> CapabilityPlan:
         members = max(buckets)
         tier = {"panel": "face", "member": "gspmd"}.get(
             placement, "classic")
-        if (tier == "classic" and cfg.serve.group_by_orography
-                and m.backend.startswith("pallas")
-                and cfg.time.scheme == "ssprk3"
-                and cfg.physics.hyperdiffusion == 0.0):
-            # Mirror EnsembleServer._impl_for: grouped single-chip
-            # buckets prefer the fused member-fold masked segment.
+        if tier in ("classic", "gspmd") and serves_fused(cfg):
             tier = "fused"
         if cfg.model.numerics == "tt":
             tier = "tt"

@@ -51,7 +51,9 @@ __all__ = [
 #: stamps, comm_probe plans and the bench contract stamp all carry it.
 #: v2 (round 18): the ``da`` axis (EnKF-cycled forecast plans) and its
 #: four composition edges entered the table.
-RULES_VERSION = 2
+#: v3: ``serve-member-jnp`` admits member placement with Pallas on
+#: grouped ssprk3 servers (the fused member fold per chip).
+RULES_VERSION = 3
 
 #: Every capability tier a config can resolve to.  ``schedule_only``
 #: tiers cannot be traced on the in-process device pool (the block
@@ -361,14 +363,19 @@ RULES: Tuple[Rule, ...] = (
            "parallelization flags — drop use_shard_map/tiles_per_edge "
            "(they configure Simulation runs)")),
     _r("serve-member-jnp", "requires",
-       [("serving", (True,)), ("placement", ("member",))],
-       [("backend", ("jnp",))],
+       [("serving", (True,)), ("placement", ("member",)),
+        ("backend", lambda v: v != "jnp")],
+       [("tier", ("fused",)), ("serve_grouping", (True,)),
+        ("scheme", ("ssprk3",)), ("nu4", (False,))],
        pointer=(
-           "placement mode 'member' partitions the vmapped classic "
-           "stepper over the member mesh axis; the fused Pallas "
-           "kernels fold every member into ONE custom call GSPMD "
-           "cannot split — set model.backend: jnp, or placement mode: "
-           "panel (the shard_map per-face kernel path)")),
+           "placement mode 'member' with a Pallas backend runs the "
+           "fused member-fold kernels on each chip's own members "
+           "(shard_map over the member mesh), which needs what the "
+           "single-chip fused bucket needs: serve.group_by_orography: "
+           "true, time.scheme: ssprk3 and physics.hyperdiffusion: 0 "
+           "— GSPMD cannot split the member-fold custom call, so "
+           "otherwise set model.backend: jnp (the partitioned vmapped "
+           "classic stepper), or placement mode: panel")),
     _r("serve-panel-grouping", "requires",
        [("serving", (True,)), ("placement", ("panel",))],
        [("serve_grouping", (True,))],
@@ -579,9 +586,10 @@ def enumerate_plans(n: int = 12, halo: int = 2, axes=None,
         # (placement -> tier): 'off' packs on one device and runs
         # either the vmapped classic or (grouped) the fused
         # member-fold masked segment; 'member' is the GSPMD
-        # member-parallel program; 'panel' the shard_map face tier.
+        # member-parallel program or (grouped) the fused member fold
+        # per chip under shard_map; 'panel' the shard_map face tier.
         serve_tiers = {"off": ("classic", "fused"),
-                       "member": ("gspmd",), "panel": ("face",)}
+                       "member": ("gspmd", "fused"), "panel": ("face",)}
         for placement in axes["placement"]:
             for tier in serve_tiers[placement]:
                 p = CapabilityPlan(

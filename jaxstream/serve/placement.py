@@ -7,14 +7,18 @@ modes, chosen by ``serve.placement.mode``:
 * ``member`` — **member-parallel**: the packed member axis shards
   across a 1-D ``('member',)`` device mesh, so a B=16 bucket on 8
   devices runs 2 members per chip.  Members never communicate, so the
-  mode adds ZERO wire traffic; the masked segment is the SAME jitted
-  program as the single-device path, compiled under member-axis
-  ``in_shardings`` — GSPMD partitions the vmapped stepper, and the
-  per-member values keep the repo's established member-batching
-  contract (h bitwise vs the single-device packed run, u at the
-  <= 1e-6 shape-dependent FMA budget — DESIGN.md "Batched ensemble
-  execution").  Requires the classic (jnp) RHS: the fused Pallas
-  kernels fold all members into one custom call GSPMD cannot split.
+  mode adds ZERO wire traffic.  With the classic (jnp) RHS the masked
+  segment is the SAME jitted program as the single-device path,
+  compiled under member-axis ``in_shardings`` — GSPMD partitions the
+  vmapped stepper, and the per-member values keep the repo's
+  established member-batching contract (h bitwise vs the
+  single-device packed run, u at the <= 1e-6 shape-dependent FMA
+  budget — DESIGN.md "Batched ensemble execution").  GSPMD cannot
+  split the fused Pallas kernels' member fold (one custom call), so a
+  grouped Pallas server runs the fold per chip instead: a
+  ``shard_map`` of the fused stepper at the local batch
+  ``B // member_shards``, with each slot's inject and extract on the
+  chip that holds it.
 * ``panel`` — **panel-sharded**: each request's six cube faces spread
   across the ``panel`` axis of the 2-D ``('panel', 'member')`` mesh
   via :func:`jaxstream.parallel.shard_cov.

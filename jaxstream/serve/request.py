@@ -164,7 +164,9 @@ class RequestResult:
     B=1 bucket, to an unbatched ``Simulation`` run of the same
     scenario.  ``latency_s`` is submit-to-completion wall time;
     ``steps_run`` how many steps actually executed (< ``nsteps`` only
-    for evictions).
+    for evictions).  ``chip`` is the member shard (chip) whose slot
+    served it: 0 on one chip, ``slot // (B // chips)`` under member
+    placement.
     """
     id: str
     ic: str
@@ -175,6 +177,7 @@ class RequestResult:
     latency_s: float
     fields: Dict[str, "object"] = dataclasses.field(default_factory=dict)
     guard_event: Optional[dict] = None
+    chip: int = 0
 
     @property
     def ok(self) -> bool:

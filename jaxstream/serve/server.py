@@ -47,12 +47,16 @@ axis of the round-7 batched steppers:
 
 **Multi-chip serving** (round 12, ``serve.placement:``): one server
 process drives a whole mesh.  ``mode: member`` shards the packed
-member axis across a 1-D ``('member',)`` device mesh — the SAME
-masked-segment program compiled under member-axis ``in_shardings``
-(GSPMD partitions the vmapped stepper; zero wire traffic; a B=16
-bucket on 8 chips runs 2 members/chip), with slot refill a
-sharding-preserving ``dynamic_update_slice`` whose incoming IC is
-``device_put`` onto the mesh per refill.  ``mode: panel`` spreads each
+member axis across a 1-D ``('member',)`` device mesh (zero wire
+traffic; a B=16 bucket on 8 chips runs 2 members/chip).  With
+``backend: jnp`` the SAME masked-segment program is compiled under
+member-axis ``in_shardings`` (GSPMD partitions the vmapped stepper),
+with slot refill a sharding-preserving ``dynamic_update_slice`` whose
+incoming IC is ``device_put`` onto the mesh per refill.  A grouped
+Pallas server runs the fused member fold per chip instead
+(:class:`_PlacedBucket`: a ``shard_map`` of the local-batch fused
+stepper, with per-chip stack, inject and extract and no collective
+anywhere).  ``mode: panel`` spreads each
 request's six faces over the 2-D ``('panel', 'member')`` mesh through
 :func:`jaxstream.parallel.shard_cov.make_sharded_cov_ensemble_stepper`
 (the PR-3 batched exchange — one ppermute per schedule stage carries
@@ -73,6 +77,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
 
 from ..config import Config, load_config
 from ..geometry.cubed_sphere import build_grid
@@ -84,7 +89,8 @@ from ..obs.monitor import HealthMonitor
 from ..obs.registry import (HOST_WAIT_BUCKETS_S, LATENCY_BUCKETS_S,
                             WALL_BUCKETS_S, MetricsRegistry)
 from ..obs.sink import TelemetrySink, run_manifest
-from ..parallel.mesh import available_devices, setup_ensemble_sharding
+from ..parallel.mesh import (available_devices, member_shard_map,
+                             member_spec, setup_ensemble_sharding)
 from ..physics import initial_conditions as ics
 from ..stepping import SCHEMES, integrate_masked, vmap_ensemble
 from ..utils import jax_compat
@@ -93,6 +99,7 @@ from .placement import PLACEMENT_MODES, BucketPlan, plan_placement
 from . import warmpool as _warmpool
 from .warmpool import HeadroomRefused
 from ..plan import rules as _plan_rules
+from ..plan.plan import serves_fused
 from ..plan.rules import RULES_VERSION as _PLAN_RULES_VERSION
 from .queue import (AdmissionRefused, QueueFull, RequestQueue,
                     ServerDraining)
@@ -126,11 +133,32 @@ def _member_nonfinite(y, axes):
     return total
 
 
+#: Member-axis position per leaf of the fused bucket's compact carry
+#: (``models.shallow_water_cov.ENSEMBLE_CARRY_AXES``).
+_FUSED_AXES = {"h": 0, "u": 1, "strips_sn": 0, "strips_we": 0}
+
+
+def _take_member(y, idx, axes):
+    """Member ``idx``'s interior state out of a batched carry."""
+    return {k: jnp.take(y[k], idx, axis=axes[k]) for k in ("h", "u")}
+
+
+def _with_member(y, idx, member, axes):
+    """The batched carry with slot ``idx`` replaced by ``member``."""
+    out = dict(y)
+    for k, ax in axes.items():
+        upd = jnp.expand_dims(member[k].astype(y[k].dtype), ax)
+        out[k] = jax.lax.dynamic_update_slice_in_dim(
+            y[k], upd, idx, axis=ax)
+    return out
+
+
 class _Slot:
     """One member slot's host bookkeeping."""
 
-    def __init__(self, req: ScenarioRequest):
+    def __init__(self, req: ScenarioRequest, chip: int = 0):
         self.req = req
+        self.chip = chip                    # member shard holding it
         self.done = 0                       # steps executed so far
 
     @property
@@ -145,6 +173,10 @@ class _Bucket:
     ``mesh``/``carry_sh``/``rep_sh`` are set when the plan is sharded
     (``stack``/``put_member``/``put_rem`` then pin their outputs to the
     mesh so every steady-state call hits the same executable)."""
+
+    #: Whether request states reach this bucket as host arrays (its
+    #: inject uploads them) rather than uploaded by their build.
+    host_leaves = False
 
     def __init__(self, group: str, B: int, seg_fn, extract_fn, inject_fn,
                  axes, stack, member_carry, plan: BucketPlan,
@@ -179,10 +211,11 @@ class _Bucket:
         return carry
 
     def put_member(self, tree):
-        """One member tree -> the inject operand (the per-slot
-        ``device_put`` of the incoming IC under placement: replicated
-        on the bucket's mesh so one inject executable serves every
-        slot)."""
+        """One member tree -> the inject operand.  Under GSPMD member
+        placement (``backend: jnp``) the incoming IC is ``device_put``
+        replicated on the bucket's mesh, so one inject executable
+        serves every slot; the fused member-placed bucket
+        (:class:`_PlacedBucket`) puts it on its slot's chip alone."""
         member = self._member_carry(tree)
         if self._rep is not None:
             member = jax.device_put(
@@ -198,6 +231,126 @@ class _Bucket:
 
     def jits(self):
         return (self.seg, self.extract, self.inject)
+
+    def chip_of(self, i: int) -> int:
+        """The member shard (chip) that holds slot ``i``."""
+        return i // self.plan.members_per_shard
+
+    def warm_slots(self) -> tuple:
+        """Slots whose extract and inject the warm-up probes: one per
+        executable the steady state runs."""
+        return (0,)
+
+    def inject_slot(self, carry, i: int, tree):
+        """``carry`` with slot ``i`` refilled from member ``tree``.  On
+        one chip the tree's ``h`` was uploaded by its build, so the
+        inject adds no host-to-device bytes."""
+        obs_trace.SPANS.count(obs_trace.INJECT_BYTES, 0)
+        return self.inject(carry, jnp.int32(i), self.put_member(tree))
+
+    def extract_slot(self, carry, i: int):
+        """Slot ``i``'s interior state (device arrays)."""
+        return self.extract(carry, jnp.int32(i))
+
+
+class _PlacedBucket(_Bucket):
+    """A fused bucket split over a ``('member',)`` mesh, ``B // chips``
+    members per chip (``serve.placement.mode: member`` on a grouped
+    Pallas server).
+
+    The masked segment is one ``shard_map`` program: each chip steps
+    its own members with the fused member-fold kernels at the local
+    batch, keeps its own slice of ``rem`` and of the health vector, and
+    issues no collective.  Everything else runs on the chip that owns
+    the slot (``i // (B // chips)``): the carry is assembled from
+    per-chip arrays, a refill uploads the member's host-built leaves to
+    that chip alone and injects them there (the strip pack inside the
+    inject), and an extract reads that chip only.  ``stack``,
+    ``extract`` and ``inject`` are single-chip jits, compiled once per
+    chip by the warm-up.  A member tree's device leaves (a family's
+    cached ``u``, built once per server) are copied to each chip once
+    and kept; a request's own leaves come from the host."""
+
+    host_leaves = True
+
+    def __init__(self, group: str, B: int, seg_fn, stack_fn, extract_fn,
+                 inject_fn, plan: BucketPlan, mesh, proof=None,
+                 cost=None):
+        super().__init__(group, B, seg_fn, extract_fn, inject_fn,
+                         _FUSED_AXES, stack_fn, None, plan, mesh=mesh,
+                         proof=proof, cost=cost)
+        self._devices = list(mesh.devices.flat)
+        self._per = plan.members_per_shard
+        self._carry_sh = {k: NamedSharding(mesh, member_spec(ax))
+                          for k, ax in self.axes.items()}
+        self._rem_sh = NamedSharding(mesh, member_spec(0))
+        self._kept: Dict[int, tuple] = {}
+
+    def jits(self):
+        return (self.seg, self.extract, self.inject, self._stack)
+
+    def warm_slots(self) -> tuple:
+        return tuple(range(0, self.B, self._per))
+
+    def _on_chip(self, a, j: int):
+        """``(leaf on chip j, host bytes uploaded)``."""
+        if isinstance(a, jax.Array):
+            # Keyed by identity and holding the array, so the key
+            # cannot be reused while the copies live.
+            kept = self._kept.setdefault(id(a), (a, {}))[1]
+            if j not in kept:
+                kept[j] = jax.device_put(a, self._devices[j])
+            return kept[j], 0
+        a = np.asarray(a)
+        return jax.device_put(a, self._devices[j]), a.nbytes
+
+    def _local(self, carry, j: int) -> dict:
+        dev = self._devices[j]
+        return {k: next(s.data for s in v.addressable_shards
+                        if s.device == dev)
+                for k, v in carry.items()}
+
+    def _assemble(self, parts) -> dict:
+        """Per-chip carries (in mesh order) -> the bucket's carry."""
+        out = {}
+        for k, ax in self.axes.items():
+            arrs = [p[k] for p in parts]
+            shape = list(arrs[0].shape)
+            shape[ax] *= len(arrs)
+            out[k] = jax.make_array_from_single_device_arrays(
+                tuple(shape), self._carry_sh[k], arrs)
+        return out
+
+    def stack(self, trees):
+        per = self._per
+        return self._assemble([
+            self._stack([{k: self._on_chip(v, j)[0] for k, v in t.items()}
+                         for t in trees[j * per:(j + 1) * per]])
+            for j in range(len(self._devices))])
+
+    def _put_on_chip(self, tree, j: int):
+        """Member tree -> (its leaves on chip ``j``, host bytes
+        uploaded)."""
+        member, nbytes = {}, 0
+        for k, v in tree.items():
+            member[k], b = self._on_chip(v, j)
+            nbytes += b
+        return member, nbytes
+
+    def put_rem(self, rem):
+        return jax.device_put(np.asarray(rem, np.int32), self._rem_sh)
+
+    def inject_slot(self, carry, i: int, tree):
+        j, k = divmod(i, self._per)
+        member, nbytes = self._put_on_chip(tree, j)
+        obs_trace.SPANS.count(obs_trace.INJECT_BYTES, nbytes)
+        parts = [self._local(carry, c) for c in range(len(self._devices))]
+        parts[j] = self.inject(parts[j], np.int32(k), member)
+        return self._assemble(parts)
+
+    def extract_slot(self, carry, i: int):
+        j, k = divmod(i, self._per)
+        return self.extract(self._local(carry, j), np.int32(k))
 
 
 class EnsembleServer:
@@ -275,7 +428,8 @@ class EnsembleServer:
                     f"{len(devs)} {p.device_type} devices exist. For "
                     f"CPU testing, start Python with XLA_FLAGS="
                     f"--xla_force_host_platform_device_count={n_dev}.")
-            if p.mode == "member" and cfg.model.backend != "jnp":
+            if (p.mode == "member" and cfg.model.backend != "jnp"
+                    and not serves_fused(cfg)):
                 _plan_rules.fail("serve-member-jnp")
             if p.mode == "panel":
                 if not self._grouping:
@@ -738,7 +892,7 @@ class EnsembleServer:
             self._ic_bases[family] = base
         return base
 
-    def _request_state(self, req: ScenarioRequest):
+    def _request_state(self, req: ScenarioRequest, host: bool = False):
         """A request's interior initial state (deterministic in seed).
 
         A seeded request runs :func:`...perturbed_member` on the
@@ -748,9 +902,12 @@ class EnsembleServer:
         (round 18): the arrays go on device as-is — byte-preserving,
         so a checkpointed member or an EnKF analysis state resubmitted
         through the gateway continues bitwise (validated at admission
-        by :meth:`validate_request`)."""
+        by :meth:`validate_request`).  With ``host`` (a bucket whose
+        inject uploads, :attr:`_Bucket.host_leaves`) the per-request
+        arrays stay on the host."""
+        put = np.ascontiguousarray if host else jnp.asarray
         if req.ic == "array":
-            return {k: jnp.asarray(v) for k, v in req.state.items()}
+            return {k: put(v) for k, v in req.state.items()}
         (h64, rhat64, href), state = self._ic_base(req.ic)
         if req.seed < 0 or req.amplitude == 0.0:
             return dict(state)
@@ -758,7 +915,7 @@ class EnsembleServer:
                                  np.random.default_rng(req.seed),
                                  req.amplitude)
         h = self.grid.interior(h.astype(state["h"].dtype))
-        return {"h": jnp.asarray(h), "u": state["u"]}
+        return {"h": put(h), "u": state["u"]}
 
     def validate_request(self, req: ScenarioRequest) -> None:
         """Admission-time deployment validation (raises ValueError).
@@ -788,10 +945,10 @@ class EnsembleServer:
                     f"{dtype} states (byte-preserving continuations "
                     f"need the exact dtype)")
 
-    def _member_tree(self, req: ScenarioRequest):
+    def _member_tree(self, req: ScenarioRequest, host: bool = False):
         """The request's member tree: interior state, plus its traced
         orography leaf on the mixed-batch path."""
-        st = self._request_state(req)
+        st = self._request_state(req, host)
         if not self._grouping:
             st = dict(st)
             st["b"] = self._b_ext(req.ic)
@@ -820,10 +977,14 @@ class EnsembleServer:
         dt, seg = cfg.time.dt, cfg.serve.segment_steps
         plan = self._plans[B]
         setup = self._setup_for(plan) if plan.sharded else None
+        proof, cost = self._bucket_stamps(impl, plan, B)
+        if impl == "fused" and plan.mode == "member":
+            return self._placed_bucket(group, B, model, plan, setup.mesh,
+                                       proof, cost)
 
         if impl == "fused":
             step = model.make_fused_step(dt, ensemble=B)
-            axes = {"h": 0, "u": 1, "strips_sn": 0, "strips_we": 0}
+            axes = _FUSED_AXES
             member_carry = model.compact_state
             stack = (lambda trees:
                      model.ensemble_compact_state(
@@ -888,44 +1049,10 @@ class EnsembleServer:
             return y, rem, _member_nonfinite(y, nf_axes)
 
         def extract_body(y, idx):
-            return {k: jnp.take(y[k], idx, axis=axes[k])
-                    for k in ("h", "u")}
+            return _take_member(y, idx, axes)
 
         def inject_body(y, idx, member):
-            out = dict(y)
-            for k, ax in axes.items():
-                upd = jnp.expand_dims(member[k].astype(y[k].dtype), ax)
-                out[k] = jax.lax.dynamic_update_slice_in_dim(
-                    y[k], upd, idx, axis=ax)
-            return out
-
-        # Round 16: the bucket's capability proof stamp — which plan
-        # this compiled masked segment implements, and whether the
-        # static matrix covers it (jaxstream.plan).
-        from ..plan.plan import CapabilityPlan
-        from ..plan.proof import build_proof
-        from ..plan.rules import normalize as plan_normalize
-
-        tier = {"fused": "fused", "vmap": "classic",
-                "vmap_b": "classic", "panel": "face"}[impl]
-        if plan.mode == "member":
-            tier = "gspmd"
-        splan = plan_normalize(CapabilityPlan(
-            tier=tier, n=cfg.grid.n, halo=self.grid.halo,
-            scheme=cfg.time.scheme, ensemble=B,
-            overlap=(cfg.parallelization.overlap_exchange
-                     and plan.mode == "panel"),
-            donate=cfg.serve.donate, serving=True,
-            placement=("off" if plan.mode == "single" else plan.mode),
-            serve_grouping=self._grouping,
-            num_devices=plan.num_devices,
-            backend=("pallas" if impl == "fused"
-                     else cfg.model.backend),
-            covariant=True))
-        proof = build_proof(splan)
-        # Round 19: the cost stamp rides next to the proof stamp —
-        # analytic per-step flops/bytes now, measured fields at warmup.
-        cost = obs_perf.build_cost(splan, plan_key=proof.plan_key)
+            return _with_member(y, idx, member, axes)
 
         donate = (0,) if cfg.serve.donate else ()
         if mesh is None:
@@ -948,24 +1075,82 @@ class EnsembleServer:
                        carry_sh=carry_sh, rep_sh=rep, proof=proof,
                        cost=cost)
 
+    def _placed_bucket(self, group: str, B: int, model, plan: BucketPlan,
+                       mesh, proof, cost) -> "_PlacedBucket":
+        """The fused bucket split over the member mesh: the masked
+        segment of the local-batch fused stepper under ``shard_map``
+        (see :class:`_PlacedBucket`)."""
+        cfg = self.config
+        dt, seg = cfg.time.dt, cfg.serve.segment_steps
+        step = model.make_fused_step(dt, ensemble=B // plan.member_shards)
+        axes = _FUSED_AXES
+        nf_axes = {k: axes[k] for k in ("h", "u")}
+
+        def seg_body(y, rem):
+            y, _, rem = integrate_masked(step, y, 0.0, rem, seg, dt, axes)
+            return y, rem, _member_nonfinite(y, nf_axes)
+
+        def stack_body(trees):
+            return model.ensemble_compact_state(model.stack_ensemble(trees))
+
+        def extract_body(y, idx):
+            return _take_member(y, idx, axes)
+
+        def inject_body(y, idx, member):
+            return _with_member(y, idx, model.compact_state(member), axes)
+
+        donate = (0,) if cfg.serve.donate else ()
+        seg_j = jax.jit(member_shard_map(seg_body, mesh, (axes, 0),
+                                         (axes, 0, 0)),
+                        donate_argnums=donate)
+        return _PlacedBucket(group, B, seg_j, jax.jit(stack_body),
+                             jax.jit(extract_body), jax.jit(inject_body),
+                             plan, mesh, proof=proof, cost=cost)
+
+    def _bucket_stamps(self, impl: str, plan: BucketPlan, B: int):
+        """The bucket's capability proof stamp (round 16: which plan
+        its compiled masked segment implements, and whether the static
+        matrix covers it) and its cost stamp (round 19: analytic
+        per-step flops/bytes now, measured fields at warmup)."""
+        from ..plan.plan import CapabilityPlan
+        from ..plan.proof import build_proof
+        from ..plan.rules import normalize as plan_normalize
+
+        cfg = self.config
+        tier = {"fused": "fused", "vmap": "classic",
+                "vmap_b": "classic", "panel": "face"}[impl]
+        if plan.mode == "member" and impl != "fused":
+            tier = "gspmd"
+        splan = plan_normalize(CapabilityPlan(
+            tier=tier, n=cfg.grid.n, halo=self.grid.halo,
+            scheme=cfg.time.scheme, ensemble=B,
+            overlap=(cfg.parallelization.overlap_exchange
+                     and plan.mode == "panel"),
+            donate=cfg.serve.donate, serving=True,
+            placement=("off" if plan.mode == "single" else plan.mode),
+            serve_grouping=self._grouping,
+            num_devices=plan.num_devices,
+            backend=("pallas" if impl == "fused"
+                     else cfg.model.backend),
+            covariant=True))
+        proof = build_proof(splan)
+        return proof, obs_perf.build_cost(splan, plan_key=proof.plan_key)
+
     def _impl_for(self, group: str, plan: BucketPlan) -> str:
         """The stepper impl of one bucket.  Panel-sharded plans run the
         shard_map ensemble stepper; mixed-orography servers run the
-        traced-b vmapped classic; grouped single-chip pallas servers
-        run the fused member-fold — and a build failure there raises
-        instead of dropping to the vmapped classic path (member-
-        sharded plans run the partitionable vmap — the backend gate in
-        __init__ already enforced jnp)."""
+        traced-b vmapped classic; grouped pallas servers run the fused
+        member-fold, on one chip or member-placed per chip (a
+        :class:`_PlacedBucket`) — and a build failure there raises
+        instead of dropping to the vmapped classic path.  Member-
+        placed ``backend: jnp`` plans run the GSPMD-partitioned vmap
+        (the gate in __init__ refused member placement with Pallas
+        wherever the fused fold does not apply)."""
         if plan.mode == "panel":
             return "panel"
         if not self._grouping:
             return "vmap_b"
-        cfg = self.config
-        fused = (plan.mode == "single"
-                 and cfg.time.scheme == "ssprk3"
-                 and cfg.model.backend.startswith("pallas")
-                 and cfg.physics.hyperdiffusion == 0.0)
-        return "fused" if fused else "vmap"
+        return "fused" if serves_fused(self.config) else "vmap"
 
     def _bucket(self, group: str, B: int) -> _Bucket:
         """The warm (group, B) runtime — built, compiled and probed on
@@ -1016,8 +1201,9 @@ class EnsembleServer:
         rem[0] = self.config.serve.segment_steps
         carry, _, nf = bk.seg(carry, bk.put_rem(rem))
         jax.block_until_ready(nf)
-        ex = bk.extract(carry, jnp.int32(0))
-        carry = bk.inject(carry, jnp.int32(0), bk.put_member(st))
+        for i in bk.warm_slots():
+            ex = bk.extract_slot(carry, i)
+            carry = bk.inject_slot(carry, i, st)
         jax.block_until_ready((ex["h"], carry["h"]))
 
     def _warm_via_pool(self, bk: _Bucket) -> Optional[str]:
@@ -1532,10 +1718,12 @@ class EnsembleServer:
                 # work.
                 for r in batch:
                     self._mark(r.id, obs_trace.PACK, sp.t0)
-            trees = [self._ic_build(r, "pack") for r in batch]
+            trees = [self._ic_build(r, "pack", bk.host_leaves)
+                     for r in batch]
             carry = bk.stack(trees + [trees[0]] * (B - len(batch)))
         slots: List[Optional[_Slot]] = (
-            [_Slot(r) for r in batch] + [None] * (B - len(batch)))
+            [_Slot(r, bk.chip_of(i)) for i, r in enumerate(batch)]
+            + [None] * (B - len(batch)))
         rem = np.zeros(B, np.int64)
         rem[:len(batch)] = [r.nsteps for r in batch]
         seg = s.segment_steps
@@ -1578,7 +1766,8 @@ class EnsembleServer:
                 r = self._pop(group)
                 if r is None:
                     break
-                prepped.append((r, self._ic_build(r, "prefetch")))
+                prepped.append(
+                    (r, self._ic_build(r, "prefetch", bk.host_leaves)))
             with obs_trace.span(obs_trace.HOST_WAIT) as sp:
                 nf_host = np.asarray(nf_fetch.resolve(),
                                      np.float64).reshape(-1)
@@ -1669,7 +1858,7 @@ class EnsembleServer:
                 with obs_trace.span(obs_trace.FINALIZE):
                     for i, sl in enumerate(slots):
                         if sl is not None and rem[i] == 0:
-                            fetch = HostFetch(bk.extract(carry, jnp.int32(i)))
+                            fetch = HostFetch(bk.extract_slot(carry, i))
                             self._finish(sl, "ok", fetch)
                             slots[i] = None
                             completed += 1
@@ -1685,11 +1874,14 @@ class EnsembleServer:
                                 r = self._pop(group)
                                 if r is None:
                                     break
-                                tree = self._ic_build(r, "late")
-                            carry = bk.inject(carry, jnp.int32(i),
-                                              bk.put_member(tree))
+                                tree = self._ic_build(r, "late",
+                                                      bk.host_leaves)
+                            with obs_trace.span(obs_trace.INJECT,
+                                                chip=bk.chip_of(i),
+                                                slot=i):
+                                carry = bk.inject_slot(carry, i, tree)
                             rem[i] = r.nsteps
-                            slots[i] = _Slot(r)
+                            slots[i] = _Slot(r, bk.chip_of(i))
                             refilled += 1
                 # Prepped requests can never be left over: free slots >=
                 # predicted completions (eviction only adds frees) and the
@@ -1796,16 +1988,17 @@ class EnsembleServer:
                              "batch; batch (B=%d) winds down without "
                              "refilling", cap0, self._active_max, B)
 
-    def _ic_build(self, req: ScenarioRequest, where: str):
+    def _ic_build(self, req: ScenarioRequest, where: str, host: bool):
         """One request's member tree as a ``serve.ic_build`` span;
         ``where`` is ``pack`` (the batch's initial pack), ``prefetch``
         (built while the segment runs) or ``late`` (inside the
         boundary).  A refill's build opens its ``serve.pack`` mark; the
-        initial pack marks the whole batch at the pack span's start."""
+        initial pack marks the whole batch at the pack span's start.
+        ``host``: the bucket's :attr:`_Bucket.host_leaves`."""
         with obs_trace.span(obs_trace.IC_BUILD, where=where) as sp:
             if where != "pack" and self._trace_on:
                 self._mark(req.id, obs_trace.PACK, sp.t0)
-            tree = self._member_tree(req)
+            tree = self._member_tree(req, host=host)
         obs_trace.SPANS.count(f"{obs_trace.IC_BUILD}.{where}")
         return tree
 
@@ -1829,10 +2022,12 @@ class EnsembleServer:
         if self._trace_on:
             self._mark(slot.req.id, obs_trace.FINALIZE_WAIT)
         self._ensure_writer().submit(
-            self._finalize, slot.req, status, slot.done, fetch, event)
+            self._finalize, slot.req, status, slot.done, fetch, event,
+            slot.chip)
 
     def _finalize(self, req: ScenarioRequest, status: str, done: int,
-                  fetch: Optional[HostFetch], event: Optional[dict]):
+                  fetch: Optional[HostFetch], event: Optional[dict],
+                  chip: int = 0):
         tr = self._traces.pop(req.id, None) if self._trace_on else None
         with obs_trace.span(obs_trace.RESULT_FETCH) as sp:
             if tr is not None:
@@ -1863,7 +2058,7 @@ class EnsembleServer:
         res = RequestResult(
             id=req.id, ic=req.ic, nsteps=req.nsteps, status=status,
             t_final=t_final, steps_run=done, latency_s=latency,
-            fields=fields, guard_event=event)
+            fields=fields, guard_event=event, chip=chip)
         if tr is not None:
             spans = tr.finish(status, t_end)
             if self._sink is not None:

@@ -18,7 +18,12 @@ keep this module fast and in-process):
     guard event names the failing member's chip;
   * the panel-sharded mode serves through the shard_map
     batched-exchange ensemble stepper (6-device mesh) at the
-    established cross-tier <= 1e-6 budget, zero steady recompiles.
+    established cross-tier <= 1e-6 budget, zero steady recompiles;
+  * a grouped Pallas server under member placement runs the fused
+    member fold per chip (plan ``serve_member+fused``, verified):
+    agreement with the single-chip fused bucket and with the classic
+    jnp stepper run solo, no collective in its executables, per-chip
+    inject/extract, zero compiles after warm-up.
 
 Configs are tiny (C8, jnp backend) — the real throughput floors
 (>= 0.8x N-chip scaling) are asserted by bench.py's
@@ -295,17 +300,249 @@ def test_panel_sharded_serving_matches_single_device():
             assert rel <= 1e-6, (rid, k, rel)
 
 
+# ------------------------------------- member-placed fused member fold
+#: The fused cells' CPU stand-in: C16, Pallas interpreted, one bucket of
+#: 8 slots — 2 members per chip on 4 of the conftest's virtual devices.
+FUSED_N, FUSED_DT = 16, 600.0
+#: Agreement with the classic jnp stepper run solo: the fused kernels
+#: and the classic XLA stepper evaluate the same discretisation in
+#: different code, so they part at f32 rounding (~1e-7 per step) and
+#: the gap grows through the dynamics: <= 3.7e-6 relative measured over
+#: these 2-8 step requests, where the bf16 stage arithmetic reads
+#: >= 1.7e-4 (h) and >= 3.7e-3 (u).
+FUSED_VS_CLASSIC = 1e-5
+
+
+def _fused_cfg(placed, backend="pallas_interpret", **serve):
+    cfg = {"grid": {"n": FUSED_N}, "time": {"dt": FUSED_DT},
+           "model": {"name": "shallow_water_cov", "backend": backend,
+                     "initial_condition": "tc5"},
+           "parallelization": {"num_devices": 1},
+           "serve": dict({"buckets": "8", "segment_steps": 1,
+                          "queue_capacity": 32,
+                          "group_by_orography": True}, **serve)}
+    if placed:
+        cfg["serve"]["placement"] = {"mode": "member", "num_devices": 4,
+                                     "device_type": "cpu"}
+    return cfg
+
+
+def _fused_serve(placed, lengths, **serve):
+    srv = EnsembleServer(_fused_cfg(placed, **serve))
+    srv.warmup(groups=("oro",))
+    warm = srv.compile_count()
+    reqs = [ScenarioRequest(id=f"f{i}", ic="tc5", nsteps=ns, seed=40 + i,
+                            amplitude=1e-3, outputs=("h", "u"))
+            for i, ns in enumerate(lengths)]
+    for r in reqs:
+        srv.submit(r)
+    srv.serve()
+    srv.close()
+    return srv, warm, reqs
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.abs(a - b).max() / np.abs(b).max())
+
+
+@pytest.fixture(scope="module")
+def fused_pair():
+    """One short ragged trace (12 requests of 1-2 steps on 8 slots, so
+    slots refill) through the single-chip fused bucket and the
+    member-placed one."""
+    _needs(4)
+    lengths = (1, 2, 1, 2, 2, 1, 2, 1, 1, 2, 2, 1)
+    return _fused_serve(False, lengths), _fused_serve(True, lengths)
+
+
+def test_member_placed_fused_matches_single_chip_bucket(fused_pair):
+    """(a) Every member served by the placed bucket agrees with the
+    single-chip fused bucket of 8 within the member-fold budget, 1e-6
+    relative (docs/DESIGN.md "Batched ensemble execution").  The
+    per-chip programs are the same kernels at the local batch, but on
+    the CPU the interpreted kernels' XLA code inside ``shard_map``
+    contracts the RK stage combination differently (one ulp in stage
+    2, measured kernel by kernel), and the dynamics grow that seed by
+    about 3e-7 relative in u per step at this size — past the budget
+    after three steps.  Hence requests of 1-2 steps here, and the solo reference
+    of the next test for longer ones."""
+    (single, _, reqs), (placed, _, _) = fused_pair
+    assert single.bucket_proofs()["oro/B8"]["plan_key"] == (
+        "serve_single+fused")
+    proof = placed.bucket_proofs()["oro/B8"]
+    assert proof["plan_key"] == "serve_member+fused"
+    assert proof["verdict"] == "verified"
+    assert placed.stats["refills"] == single.stats["refills"] == 4
+    for r in reqs:
+        a, b = placed.results[r.id], single.results[r.id]
+        assert a.status == b.status == "ok"
+        assert a.steps_run == b.steps_run == r.nsteps
+        for k in ("h", "u"):
+            assert _rel(a.fields[k], b.fields[k]) <= 1e-6, (r.id, k)
+
+
+def test_member_placed_fused_matches_solo_classic_stepper():
+    """(b) Longer requests (2-8 steps, 2-step segments) served member-
+    placed agree with the same request run solo on the plain f32
+    classic jnp stepper through ``Simulation`` within
+    FUSED_VS_CLASSIC; the bf16 stage arithmetic, run solo the same
+    way, fails that tolerance on every request."""
+    import jax.numpy as jnp
+
+    from jaxstream.simulation import Simulation
+
+    _needs(4)
+    lengths = (8, 2, 4, 8, 4, 2, 8, 2, 4, 8)
+    srv, _, reqs = _fused_serve(True, lengths, segment_steps=2)
+
+    def solo_runner(backend, precision=None):
+        cfg = _fused_cfg(False, backend=backend)
+        del cfg["serve"]
+        if precision:
+            cfg["precision"] = {"stage": precision}
+        sim = Simulation(cfg)
+
+        def run(req):
+            st = srv._request_state(req)
+            sim.state = {k: jnp.array(st[k], jnp.float32, copy=True)
+                         for k in ("h", "u")}
+            sim.step_count, sim.t = 0, 0.0
+            sim.run(req.nsteps)
+            return {k: np.asarray(sim.state[k]) for k in ("h", "u")}
+        return sim, run
+
+    sim_c, classic = solo_runner("jnp")
+    sim_b, bf16 = solo_runner("pallas_interpret", "bf16")
+    try:
+        for r in reqs:
+            got = srv.results[r.id]
+            assert got.status == "ok" and got.steps_run == r.nsteps
+            ref, low = classic(r), bf16(r)
+            for k in ("h", "u"):
+                assert _rel(got.fields[k], ref[k]) <= FUSED_VS_CLASSIC, (
+                    r.id, k)
+            assert max(_rel(low[k], ref[k]) for k in ("h", "u")) > (
+                FUSED_VS_CLASSIC), r.id
+    finally:
+        sim_c.close()
+        sim_b.close()
+
+
+_COLLECTIVES = ("all-gather", "all-reduce", "all-to-all",
+                "collective-permute", "reduce-scatter",
+                "collective-broadcast", "all_gather", "all_reduce",
+                "all_to_all", "collective_permute", "reduce_scatter",
+                "collective_broadcast")
+
+
+def _collectives(text):
+    return [c for c in _COLLECTIVES if c in text]
+
+
+def test_member_placed_executables_hold_no_collective(fused_pair):
+    """(c) The placed bucket's masked segment (one ``shard_map``
+    program over the 4-chip mesh), inject and extract (one chip each)
+    hold no collective, lowered or compiled; the segment's carry,
+    ``rem`` and health vector stay split along the member axis."""
+    _, (placed, _, _) = fused_pair
+    bk = placed._buckets[("oro", 8)]
+    st = placed._warm_member_tree("oro")
+    carry = bk.stack([st] * 8)
+    rem = bk.put_rem(np.arange(8))
+    lowered = bk.seg.lower(carry, rem)
+    assert _collectives(lowered.as_text()) == []
+    assert _collectives(lowered.compile().as_text()) == []
+    out, rem2, nf = bk.seg(carry, rem)
+    for a in (rem2, nf, out["h"], out["strips_we"]):
+        assert len(a.sharding.device_set) == 4
+        assert a.sharding.spec[0] == "member"
+    assert out["u"].sharding.spec[1] == "member"
+    local = bk._local(out, 1)
+    member = bk._put_on_chip(st, 1)[0]
+    for fn, args in ((bk.inject, (local, np.int32(0), member)),
+                     (bk.extract, (local, np.int32(1)))):
+        low = fn.lower(*args)
+        assert _collectives(low.as_text()) == []
+        assert _collectives(low.compile().as_text()) == []
+
+
+def test_member_placed_refills_are_per_chip_and_compile_free(fused_pair):
+    """(d) Zero compiles after warm-up through a trace whose refills
+    cycle every slot, and a direct round of injects into every slot:
+    each member is read back unchanged from its own slot, on the chip
+    that owns it, each inject uploads one member's ``h`` alone, and
+    each result names its chip."""
+    from jaxstream.obs import trace as obs_trace
+
+    _, (placed, warm, reqs) = fused_pair
+    assert warm == placed.stats["warmup_compiles"] > 0
+    assert placed.compile_count() == warm
+    # Each result names the chip whose slot served it: the first pack
+    # fills slots 0-7 (2 per chip), the 1-step members free slots 0, 2,
+    # 5 and 7, and the four refills land there.
+    assert [placed.results[r.id].chip for r in reqs] == [
+        0, 0, 1, 1, 2, 2, 3, 3, 0, 1, 2, 3]
+    bk = placed._buckets[("oro", 8)]
+    assert bk.host_leaves
+    trees = [placed._member_tree(r, host=True) for r in reqs[:8]]
+    assert all(isinstance(t["h"], np.ndarray) for t in trees)
+    carry = bk.stack([placed._warm_member_tree("oro")] * 8)
+    before = obs_trace.SPANS.summary()["counters"].get(
+        obs_trace.INJECT_BYTES, 0)
+    for i, t in enumerate(trees):
+        carry = bk.inject_slot(carry, i, t)
+    added = obs_trace.SPANS.summary()["counters"][
+        obs_trace.INJECT_BYTES] - before
+    assert added == sum(t["h"].nbytes for t in trees)
+    for i, t in enumerate(trees):
+        got = bk.extract_slot(carry, i)
+        chip = placed._devices[i // 2]
+        assert bk.chip_of(i) == i // 2
+        for k in ("h", "u"):
+            assert got[k].devices() == {chip}, (i, k)
+            np.testing.assert_array_equal(np.asarray(got[k]),
+                                          np.asarray(t[k]),
+                                          err_msg=(i, k))
+    assert placed.compile_count() == warm
+
+
+def test_member_placed_fused_plan_is_enumerated_and_verified():
+    """(e) ``serve_member+fused`` is in the enumerated plan space and a
+    plan of it (as ``plan_for`` resolves the deployment) is minted
+    ``verified``; the GSPMD member plan stays ``backend: jnp``'s."""
+    from jaxstream.plan import enumerate_plans, plan_for
+    from jaxstream.plan.proof import build_proof
+
+    keys = {p.key() for p in enumerate_plans()}
+    assert {"serve_member+fused", "serve_member+gspmd"} <= keys
+    plan = plan_for(_fused_cfg(True), serving=True)
+    assert plan.key() == "serve_member+fused"
+    assert build_proof(plan).verdict == "verified"
+    assert plan_for(_fused_cfg(True, backend="jnp"),
+                    serving=True).key() == "serve_member+gspmd"
+
+
 # ------------------------------------------------------------ validation
 def test_placement_config_validation():
     with pytest.raises(ValueError, match="placement.mode"):
         EnsembleServer(_cfg(placement={"mode": "tiles"}))
-    # member placement partitions the classic stepper — the fused
-    # member-fold is one custom call GSPMD cannot split.
-    with pytest.raises(ValueError, match="backend"):
+    # member placement with Pallas runs the fused member fold per
+    # chip, which needs the grouped server; ungrouped, the pointer
+    # names the rule's conditions and the jnp way out.
+    with pytest.raises(ValueError, match="group_by_orography") as ei:
         EnsembleServer(_cfg(placement={"mode": "member",
                                        "num_devices": 2},
                             model={"backend": "pallas",
                                    "name": "shallow_water_cov"}))
+    assert ei.value.violations[0].rule == "serve-member-jnp"
+    assert "model.backend: jnp" in str(ei.value)
+    srv = EnsembleServer(_cfg(placement={"mode": "member",
+                                         "num_devices": 2},
+                              model={"backend": "pallas"},
+                              serve={"group_by_orography": True}))
+    assert srv._impl_for("oro", srv._plans[4]) == "fused"
+    srv.close()
     # panel placement bakes orography per device: grouped mode only.
     with pytest.raises(ValueError, match="group_by_orography"):
         EnsembleServer(_cfg(placement={"mode": "panel",

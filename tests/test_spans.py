@@ -170,8 +170,8 @@ def test_ic_base_built_once_per_family():
     built = {}
     member_tree = srv._member_tree
 
-    def record(req):
-        built[req.id] = tree = member_tree(req)
+    def record(req, **kw):
+        built[req.id] = tree = member_tree(req, **kw)
         return tree
 
     srv._member_tree = record

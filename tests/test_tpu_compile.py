@@ -34,10 +34,10 @@ V5E_HBM_BYTES = 16 * 1024 ** 3
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One chip of a described v5e:2x2, compiled for in f32 with the
-    persistent cache off (a topology compile cannot be read back
-    without a chip, and x64 index types are not legal in Mosaic)."""
+def topo():
+    """A described v5e:2x2, compiled for in f32 with the persistent
+    cache off (a topology compile cannot be read back without a chip,
+    and x64 index types are not legal in Mosaic)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -54,11 +54,17 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
         finally:
             for n, v in saved.items():
                 jax.config.update(n, v)
             compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One chip of the described host."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _model(n, halo=2, **kw):
@@ -114,3 +120,58 @@ def test_main_path_compiles_for_v5e(one_chip, case):
              + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes
              - mem.alias_size_in_bytes)
     assert 0 < total < V5E_HBM_BYTES, (case, total)
+
+
+def test_member_placed_serving_segment_compiles_for_v5e_2x2(topo):
+    """The member-placed fused bucket's masked segment (the serving
+    tier's ``shard_map`` of the local-batch member fold, 2 members per
+    chip at C96) compiles for the four chips of the described host,
+    holds the stage kernels and no collective, and its per-chip
+    inject and extract compile for one chip."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+
+    from jaxstream.parallel.mesh import member_shard_map, member_spec
+    from jaxstream.serve.server import (_FUSED_AXES, _member_nonfinite,
+                                        _with_member, _take_member)
+    from jaxstream.stepping import integrate_masked
+
+    mesh = Mesh(np.array(topo.devices), ("member",))
+    chips, per = len(topo.devices), 2
+    model, state = _model(96)
+    step = model.make_fused_step(75.0, ensemble=per)
+    axes = _FUSED_AXES
+
+    def seg_body(y, rem):
+        y, _, rem = integrate_masked(step, y, 0.0, rem, 4, 75.0, axes)
+        return y, rem, _member_nonfinite(y, {"h": 0, "u": 1})
+
+    B = chips * per
+    carry = jax.eval_shape(model.ensemble_compact_state, {
+        "h": jnp.stack([state["h"]] * B),
+        "u": jnp.stack([state["u"]] * B, axis=1)})
+    args = {k: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=NamedSharding(mesh, member_spec(axes[k])))
+        for k, s in carry.items()}
+    rem = jax.ShapeDtypeStruct((B,), jnp.int32,
+                               sharding=NamedSharding(mesh, member_spec(0)))
+    seg = jax.jit(member_shard_map(seg_body, mesh, (axes, 0), (axes, 0, 0)))
+    text = seg.lower(args, rem).compile().as_text()
+    assert "tpu_custom_call" in text
+    collectives = ("all-gather", "all-reduce", "all-to-all",
+                   "collective-permute", "reduce-scatter")
+    assert [c for c in collectives if c in text] == []
+
+    one = SingleDeviceSharding(topo.devices[1])
+    local = {k: jax.ShapeDtypeStruct(
+        s.shape[:axes[k]] + (per,) + s.shape[axes[k] + 1:], s.dtype,
+        sharding=one) for k, s in carry.items()}
+    member = {k: jax.ShapeDtypeStruct(state[k].shape, jnp.float32,
+                                      sharding=one) for k in ("h", "u")}
+    idx = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
+    for fn, a in ((lambda y, i, m: _with_member(
+                       y, i, model.compact_state(m), axes),
+                   (local, idx, member)),
+                  (lambda y, i: _take_member(y, i, axes), (local, idx))):
+        text = jax.jit(fn).lower(*a).compile().as_text()
+        assert [c for c in collectives if c in text] == []
